@@ -48,7 +48,7 @@ from repro.core import compile_chain, lamb, msgd, sngm
 from repro.core import transform as T
 from repro.core.multi_tensor import FlatOptState, mesh_shards, unflatten
 from repro.core.schedules import constant
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.tracker.counters import (capture_donation_warnings,
                                     launches_per_step, param_bytes_live)
 
@@ -88,7 +88,7 @@ def time_call(fn, *args, iters=5):
 def run(quick: bool = False, json_path: str | None = None):
     shapes = SHAPES_QUICK if quick else SHAPES
     iters = 3 if quick else 5
-    mesh = make_host_mesh(2, 2)
+    mesh = make_mesh((2, 2), ("data", "model"))
     assert mesh_shards(mesh) == 4, dict(mesh.shape)
     params = make_tree(0, shapes)
     grads = [make_tree(1 + t, shapes, 3.0) for t in range(2)]

@@ -24,6 +24,7 @@ from repro.core.optim import OptState, builder_accepts, optimizer_names
 from repro.core.schedules import poly_power
 from repro.data import (DiskShardedSource, PrefetchIterator, StreamingLoader,
                         SyntheticLM, device_put_batch)
+from repro.launch.mesh import make_mesh
 from repro.models import model_defs
 from repro.models.param import count, materialize
 from repro.models.runtime import Runtime
@@ -65,7 +66,7 @@ def main():
     print(f"arch={cfg.name} params={count(defs):,} devices={len(jax.devices())}")
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model")) if n_dev > 1 else None
+    mesh = make_mesh((n_dev, 1), ("data", "model")) if n_dev > 1 else None
     rt = Runtime(mesh=mesh, remat=False) if mesh else Runtime(mesh=None, remat=False)
     if mesh:
         psh = param_shardings(defs, mesh)

@@ -318,11 +318,11 @@ def _engine_mesh(layout: TreeLayout, mesh):
 
 
 def _shmap(mesh, f, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    # check_rep=False: outputs include all_gather-ed partial vectors that
-    # ARE replicated, but 0.4.x's replication inference cannot prove it.
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    # check_vma=False: outputs include all_gather-ed partial vectors that
+    # ARE replicated, but the Pallas calls inside the body carry no
+    # varying-manual-axes annotation, so the checker cannot prove it.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _chunk_sumsq(x, p=None, *, wd: float = 0.0, backend: str = "pallas",

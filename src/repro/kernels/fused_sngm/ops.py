@@ -1,22 +1,18 @@
 """jit'd tree-level wrapper used by ``repro.core.optim.sngm(use_pallas=True)``.
 
-On non-TPU backends the kernel runs in interpret mode (correctness path);
+On the CPU backend the kernel runs in interpret mode (correctness path);
 numerics match ref.py / the jnp optimizer exactly (float32 math).
 """
 from __future__ import annotations
 
 import jax
 
-from repro.kernels import record_launches
+from repro.kernels import interpret_mode, record_launches
 from repro.kernels.fused_sngm.kernel import fused_sngm_update
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def fused_sngm_tree(params, grads, momentum, inv_norm, beta: float, lr):
-    interp = _interpret()
+    interp = interpret_mode()
     new_p, new_u = {}, {}
     flat_p = jax.tree_util.tree_flatten_with_path(params)[0]
     treedef = jax.tree_util.tree_structure(params)

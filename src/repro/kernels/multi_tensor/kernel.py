@@ -53,15 +53,8 @@ behaviour under XLA fusion) byte-stable across all chain variants.
 
 Layout: buffers are viewed as (n_chunks, CHUNK) rows; the grid walks
 tiles of TILE_ROWS rows.  Coefficients/partials ride in (TILE_ROWS, 1)
-blocks — fine in interpret mode and on recent Mosaic (last-dim-1 gets a
-masked relayout).  For a target TPU whose Mosaic build rejects the
-last-dim-1 layout, set ``lane_pad=True`` (or export
-``REPRO_MT_LANE_PAD=1``): coefficient/partial blocks are padded to the
-full lane width (``LANE=128``) — the coefficient is replicated across
-lanes on the host, partials are broadcast-stored across lanes in the
-kernel and lane 0 is sliced back out — with bitwise-identical results
-(each lane carries the same f32 value; asserted in
-tests/test_multi_tensor.py).
+blocks, which Mosaic lowers with a masked relayout (the TPU compile of
+every kernel is kept in tests/test_tpu_compile.py).
 
 In-place residency: the update passes declare ``input_output_aliases``
 (p->p_new, u->u_new, m->m_new, v->v_new), so when the caller's buffers
@@ -74,7 +67,6 @@ unaffected.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -84,38 +76,6 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 1024        # elements per row == per-coefficient granularity
 TILE_ROWS = 64      # rows per grid step: 64*1024*4B = 256 KiB f32 per operand
 TILE = TILE_ROWS * CHUNK
-LANE = 128          # TPU lane width: coefficient-block width under lane_pad
-
-
-def _lane_pad_default() -> bool:
-    """Env-switchable default for the lane-width padding of coefficient /
-    partial blocks (real-TPU Mosaic builds that reject (rows, 1))."""
-    return os.environ.get("REPRO_MT_LANE_PAD", "0").lower() not in (
-        "0", "", "false")
-
-
-def _coeff_width(lane_pad: bool) -> int:
-    return LANE if lane_pad else 1
-
-
-def _expand_coeff(a: jnp.ndarray, lane_pad: bool) -> jnp.ndarray:
-    """Host-side: (n_chunks,) f32 -> the (n_chunks, width) block the kernel
-    reads.  Lane replication keeps every lane bit-identical to lane 0."""
-    col = a.reshape(-1, 1)
-    if not lane_pad:
-        return col
-    return jnp.broadcast_to(col, (col.shape[0], LANE))
-
-
-def _store_partial(ref, s: jnp.ndarray) -> None:
-    """Kernel-side: store a (rows, 1) partial into a (rows, width) block,
-    broadcasting the value across lanes when lane-padded."""
-    ref[...] = jnp.broadcast_to(s, ref.shape)
-
-
-def _partials_out(out: jnp.ndarray) -> jnp.ndarray:
-    """Host-side: (n_chunks, width) partial block -> (n_chunks,) lane 0."""
-    return out[:, 0]
 
 
 def _tile_rows(n_chunks: int, interpret: bool) -> int:
@@ -144,17 +104,16 @@ def _decay(g, p, *, wd: float, cast_g_first: bool):
 
 def _sumsq_raw_kernel(x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    _store_partial(o_ref, jnp.sum(jnp.square(x), axis=1, keepdims=True))
+    o_ref[...] = jnp.sum(jnp.square(x), axis=1, keepdims=True)
 
 
 def _sumsq_decayed_kernel(g_ref, p_ref, o_ref, *, wd):
     ge = _decay(g_ref[...], p_ref[...], wd=wd, cast_g_first=False)
-    _store_partial(o_ref, jnp.sum(jnp.square(ge), axis=1, keepdims=True))
+    o_ref[...] = jnp.sum(jnp.square(ge), axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("wd", "interpret", "lane_pad"))
-def chunk_sumsq(x, p=None, *, wd: float = 0.0, interpret: bool = False,
-                lane_pad: bool = False):
+@functools.partial(jax.jit, static_argnames=("wd", "interpret"))
+def chunk_sumsq(x, p=None, *, wd: float = 0.0, interpret: bool = False):
     """Per-chunk sum of squares of ``x`` (or of ``x + wd*p`` when ``p`` is
     given).  ``x``: flat (n,) with n % TILE == 0.  Returns f32 (n/CHUNK,)."""
     assert x.ndim == 1 and x.size % TILE == 0, x.shape
@@ -162,10 +121,9 @@ def chunk_sumsq(x, p=None, *, wd: float = 0.0, interpret: bool = False,
     n_chunks = x2.shape[0]
     rows = _tile_rows(n_chunks, interpret)
     grid = n_chunks // rows
-    width = _coeff_width(lane_pad)
     tile = pl.BlockSpec((rows, CHUNK), lambda i: (i, 0))
-    otile = pl.BlockSpec((rows, width), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((n_chunks, width), jnp.float32)
+    otile = pl.BlockSpec((rows, 1), lambda i: (i, 0))
+    out_shape = jax.ShapeDtypeStruct((n_chunks, 1), jnp.float32)
     if p is None or wd == 0.0:
         out = pl.pallas_call(
             _sumsq_raw_kernel, grid=(grid,),
@@ -178,7 +136,7 @@ def chunk_sumsq(x, p=None, *, wd: float = 0.0, interpret: bool = False,
             in_specs=[tile, tile], out_specs=otile, out_shape=out_shape,
             interpret=interpret,
         )(x2, p.reshape(-1, CHUNK))
-    return _partials_out(out)
+    return out.ravel()
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +147,7 @@ def _update_kernel(c_ref, a_ref, p_ref, g_ref, u_ref,
                    po_ref, uo_ref, usq_ref, *, beta, wd, cast_g_first,
                    nesterov, apply):
     ge = _decay(g_ref[...], p_ref[...], wd=wd, cast_g_first=cast_g_first)
-    a = a_ref[:, 0:1]                    # (TILE_ROWS, 1), broadcasts per row
+    a = a_ref[...]                       # (TILE_ROWS, 1), broadcasts per row
     u_new = beta * u_ref[...] + a * ge
     # nesterov look-ahead: the applied direction re-adds the scaled
     # gradient on top of the NEW momentum (the interpreter's second
@@ -202,16 +160,15 @@ def _update_kernel(c_ref, a_ref, p_ref, g_ref, u_ref,
         # deferred apply (a suffix stage — e.g. a trailing clip — still
         # reads the effective direction): first output carries ``out``
         po_ref[...] = out
-    _store_partial(usq_ref, jnp.sum(jnp.square(out), axis=1, keepdims=True))
+    usq_ref[...] = jnp.sum(jnp.square(out), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "wd", "cast_g_first",
                                              "nesterov", "apply",
-                                             "interpret", "lane_pad"))
+                                             "interpret"))
 def fused_update(p, g, u, a_chunk, c, *, beta: float, wd: float,
                  cast_g_first: bool = False, nesterov: bool = False,
-                 apply: bool = True, interpret: bool = False,
-                 lane_pad: bool = False):
+                 apply: bool = True, interpret: bool = False):
     """Whole-bucket fused optimizer update.
 
     p: flat (n,) in the bucket dtype; g: flat (n,) gradient buffer (bucket
@@ -236,9 +193,8 @@ def fused_update(p, g, u, a_chunk, c, *, beta: float, wd: float,
     assert a_chunk.shape == (n_chunks,), a_chunk.shape
     rows = _tile_rows(n_chunks, interpret)
     grid = n_chunks // rows
-    width = _coeff_width(lane_pad)
     tile = pl.BlockSpec((rows, CHUNK), lambda i: (i, 0))
-    ctile = pl.BlockSpec((rows, width), lambda i: (i, 0))
+    ctile = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     cs = jnp.reshape(c, (1,)).astype(jnp.float32)
     po_dtype = p.dtype if apply else jnp.float32
     aliases = {2: 0, 4: 1} if apply else {4: 1}
@@ -252,26 +208,25 @@ def fused_update(p, g, u, a_chunk, c, *, beta: float, wd: float,
         out_specs=[tile, tile, ctile],
         out_shape=[jax.ShapeDtypeStruct((n_chunks, CHUNK), po_dtype),
                    jax.ShapeDtypeStruct((n_chunks, CHUNK), jnp.float32),
-                   jax.ShapeDtypeStruct((n_chunks, width), jnp.float32)],
+                   jax.ShapeDtypeStruct((n_chunks, 1), jnp.float32)],
         input_output_aliases=aliases,          # p -> p_new, u -> u_new
         interpret=interpret,
-    )(cs, _expand_coeff(a_chunk, lane_pad), p.reshape(-1, CHUNK),
+    )(cs, a_chunk.reshape(-1, 1), p.reshape(-1, CHUNK),
       g.reshape(-1, CHUNK), u.reshape(-1, CHUNK))
-    return po.ravel(), uo.ravel(), _partials_out(usq)
+    return po.ravel(), uo.ravel(), usq.ravel()
 
 
 def _scale_apply_kernel(c_ref, a_ref, p_ref, g_ref, po_ref, ssq_ref):
     """Per-chunk-scaled apply (LAMB's second launch): the expression
     mirrors the interpreter's scale_by_trust_ratio (ratio * u) ->
     scale_by_schedule (lr * .) -> apply (w - .) stages exactly."""
-    s = a_ref[:, 0:1] * g_ref[...]       # (TILE_ROWS, 1) a broadcasts
+    s = a_ref[...] * g_ref[...]          # (TILE_ROWS, 1) a broadcasts
     po_ref[...] = (p_ref[...] - c_ref[0] * s).astype(po_ref.dtype)
-    _store_partial(ssq_ref, jnp.sum(jnp.square(s), axis=1, keepdims=True))
+    ssq_ref[...] = jnp.sum(jnp.square(s), axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "lane_pad"))
-def scale_apply(p, g, a_chunk, c, *, interpret: bool = False,
-                lane_pad: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scale_apply(p, g, a_chunk, c, *, interpret: bool = False):
     """Whole-bucket scale-and-apply: ``p <- (p - c * (a * g)).astype``.
 
     p: flat (n,) in the bucket dtype; g: flat (n,) f32 direction;
@@ -286,9 +241,8 @@ def scale_apply(p, g, a_chunk, c, *, interpret: bool = False,
     assert a_chunk.shape == (n_chunks,), a_chunk.shape
     rows = _tile_rows(n_chunks, interpret)
     grid = n_chunks // rows
-    width = _coeff_width(lane_pad)
     tile = pl.BlockSpec((rows, CHUNK), lambda i: (i, 0))
-    ctile = pl.BlockSpec((rows, width), lambda i: (i, 0))
+    ctile = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     cs = jnp.reshape(c, (1,)).astype(jnp.float32)
     po, ssq = pl.pallas_call(
         _scale_apply_kernel,
@@ -297,12 +251,12 @@ def scale_apply(p, g, a_chunk, c, *, interpret: bool = False,
                   ctile, tile, tile],
         out_specs=[tile, ctile],
         out_shape=[jax.ShapeDtypeStruct((n_chunks, CHUNK), p.dtype),
-                   jax.ShapeDtypeStruct((n_chunks, width), jnp.float32)],
+                   jax.ShapeDtypeStruct((n_chunks, 1), jnp.float32)],
         input_output_aliases={2: 0},           # p -> p_new
         interpret=interpret,
-    )(cs, _expand_coeff(a_chunk, lane_pad), p.reshape(-1, CHUNK),
+    )(cs, a_chunk.reshape(-1, 1), p.reshape(-1, CHUNK),
       g.reshape(-1, CHUNK))
-    return po.ravel(), _partials_out(ssq)
+    return po.ravel(), ssq.ravel()
 
 
 # ---------------------------------------------------------------------------
@@ -320,7 +274,7 @@ def _adam_kernel(b_ref, p_ref, g_ref, m_ref, v_ref,
     including the cast orders (wd*p in the param dtype, then f32 add)."""
     g = g_ref[...]
     g32 = g.astype(jnp.float32)
-    _store_partial(gsq_ref, jnp.sum(jnp.square(g32), axis=1, keepdims=True))
+    gsq_ref[...] = jnp.sum(jnp.square(g32), axis=1, keepdims=True)
     m_new = b1 * m_ref[...] + (1 - b1) * g32
     v_new = b2 * v_ref[...] + (1 - b2) * jnp.square(g32)
     u = (m_new / b_ref[0]) / (jnp.sqrt(v_new / b_ref[1]) + eps)
@@ -329,17 +283,15 @@ def _adam_kernel(b_ref, p_ref, g_ref, m_ref, v_ref,
     mo_ref[...] = m_new
     vo_ref[...] = v_new
     uo_ref[...] = u
-    _store_partial(usq_ref, jnp.sum(jnp.square(u), axis=1, keepdims=True))
-    _store_partial(psq_ref,
-                   jnp.sum(jnp.square(p_ref[...].astype(jnp.float32)),
-                           axis=1, keepdims=True))
+    usq_ref[...] = jnp.sum(jnp.square(u), axis=1, keepdims=True)
+    psq_ref[...] = jnp.sum(jnp.square(p_ref[...].astype(jnp.float32)),
+                           axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd",
-                                             "interpret", "lane_pad"))
+                                             "interpret"))
 def adam_update(p, g, m, v, bc1, bc2, *, b1: float, b2: float,
-                eps: float, wd: float = 0.0, interpret: bool = False,
-                lane_pad: bool = False):
+                eps: float, wd: float = 0.0, interpret: bool = False):
     """Whole-bucket fused Adam-moment pass (LAMB's first launch).
 
     p, g: flat (n,) in the bucket dtype; m, v: flat (n,) f32 moments;
@@ -356,13 +308,12 @@ def adam_update(p, g, m, v, bc1, bc2, *, b1: float, b2: float,
     n_chunks = p.size // CHUNK
     rows = _tile_rows(n_chunks, interpret)
     grid = n_chunks // rows
-    width = _coeff_width(lane_pad)
     tile = pl.BlockSpec((rows, CHUNK), lambda i: (i, 0))
-    ctile = pl.BlockSpec((rows, width), lambda i: (i, 0))
+    ctile = pl.BlockSpec((rows, 1), lambda i: (i, 0))
     bs = jnp.stack([jnp.asarray(bc1, jnp.float32),
                     jnp.asarray(bc2, jnp.float32)])
     flat = jax.ShapeDtypeStruct((n_chunks, CHUNK), jnp.float32)
-    part = jax.ShapeDtypeStruct((n_chunks, width), jnp.float32)
+    part = jax.ShapeDtypeStruct((n_chunks, 1), jnp.float32)
     mo, vo, uo, usq, psq, gsq = pl.pallas_call(
         functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps, wd=wd),
         grid=(grid,),
@@ -375,4 +326,4 @@ def adam_update(p, g, m, v, bc1, bc2, *, b1: float, b2: float,
     )(bs, p.reshape(-1, CHUNK), g.reshape(-1, CHUNK),
       m.reshape(-1, CHUNK), v.reshape(-1, CHUNK))
     return (mo.ravel(), vo.ravel(), uo.ravel(),
-            _partials_out(usq), _partials_out(psq), _partials_out(gsq))
+            usq.ravel(), psq.ravel(), gsq.ravel())

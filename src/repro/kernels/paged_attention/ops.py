@@ -1,11 +1,9 @@
 """jit'd wrapper: Pallas paged decode attention on TPU, interpret mode
-elsewhere (the kernel body runs in Python on CPU)."""
-import jax
-
+on the CPU backend (the kernel body runs in Python)."""
+from repro.kernels import interpret_mode
 from repro.kernels.paged_attention.kernel import paged_decode_attention
 
 
 def paged_attention(q, kp, vp, bt, pos, **kw):
     return paged_decode_attention(q, kp, vp, bt, pos,
-                                  interpret=jax.default_backend() != "tpu",
-                                  **kw)
+                                  interpret=interpret_mode(), **kw)

@@ -12,6 +12,13 @@ launcher and checkpoint layer route through.  ``make_train_mesh`` is the
 launcher's one mesh constructor: flags land here instead of ad-hoc
 ``jax.make_mesh`` calls, so the pod axis and the single-device
 degenerate case are handled in exactly one place.
+
+Every mesh in the repo is built by ``make_mesh``, which marks all axes
+``Auto``.  ``jax.make_mesh`` defaults to ``Explicit`` axes, on which
+``with_sharding_constraint`` (``Runtime.constrain``, the gradient and
+flat-buffer constraints of the train step) is refused: the model and
+engine code leave the partitioning of everything they do not pin to
+the SPMD partitioner, which is what ``Auto`` means.
 """
 from __future__ import annotations
 
@@ -19,6 +26,13 @@ import os
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axis_names) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (see the module doc)."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def init_distributed(*, coordinator_address: Optional[str] = None,
@@ -76,9 +90,9 @@ def make_train_mesh(data: int = 0, model: int = 1,
     n_dev = len(jax.devices())
     n_data = data or max(1, n_dev // (model * pod))
     if pod > 1:
-        return jax.make_mesh((pod, n_data, model), ("pod", "data", "model"))
+        return make_mesh((pod, n_data, model), ("pod", "data", "model"))
     if n_data * model > 1:
-        return jax.make_mesh((n_data, model), ("data", "model"))
+        return make_mesh((n_data, model), ("data", "model"))
     return None
 
 
@@ -87,12 +101,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips; "pod" is pure DP."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over however many (host) devices exist — tests/examples."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def data_axes_of(mesh: jax.sharding.Mesh):

@@ -8,7 +8,11 @@
     per token).
 
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b \
-        --slots 4 --requests 10 --max-new 12 --temperature 0.7
+        --reduced --slots 4 --requests 10 --max-new 12 --temperature 0.7
+
+``--reduced`` serves the smoke-scale variant; ``--n-layers N`` cuts depth
+at the published widths (``--arch yi-9b --n-layers 2`` fits one chip).
+``main`` returns the finished requests.
 """
 from __future__ import annotations
 
@@ -21,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCHS, get_config, smoke_variant
+from repro.launch.common import (add_model_args, enable_compile_cache,
+                                 model_config)
 from repro.models import model_defs
 from repro.models.param import materialize
 from repro.models.runtime import CPU_RUNTIME
@@ -141,9 +146,9 @@ def _report(finished, dt: float, steps: int, label: str):
               f"mean {np.mean(lats) * 1e3:.0f}ms")
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="deepseek-7b", choices=sorted(ARCHS))
+    add_model_args(ap, "deepseek-7b")
     ap.add_argument("--engine", default="paged", choices=["paged", "dense"])
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
@@ -157,9 +162,10 @@ def main():
     ap.add_argument("--blocks", type=int, default=0,
                     help="KV pool blocks (0 = enough for all slots)")
     ap.add_argument("--decode-chunk", type=int, default=4)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    cfg = smoke_variant(get_config(args.arch))
+    enable_compile_cache()
+    cfg = model_config(args, "serve")
     params = materialize(model_defs(cfg), jax.random.PRNGKey(0))
     ctx = args.prompt_len + args.max_new
 
@@ -186,7 +192,7 @@ def main():
         print(f"[serve:paged] peak blocks {sched.stats['peak_used_blocks']}"
               f"/{n_blocks - 1}, preemptions {sched.stats['preemptions']}, "
               f"compiles {sched.compile_counts()}")
-        return
+        return finished
 
     queue = [Request(i, jnp.asarray(p)[None], args.max_new,
                      t_submit=time.monotonic()) for i, p in enumerate(prompts)]
@@ -204,6 +210,7 @@ def main():
             finished += b.decode_step()
             steps += 1
     _report(finished, time.monotonic() - t0, steps, "dense")
+    return finished
 
 
 if __name__ == "__main__":

@@ -9,6 +9,11 @@ compile-only mode.
     PYTHONPATH=src python -m repro.launch.train --arch yi-9b \
         --steps 50 --batch 8 --seq 128 --reduced
 
+``--n-layers N`` cuts depth at the published widths (what fits one
+chip: ``--arch yi-9b --n-layers 1`` at B=8, S=1024); ``--hlo-out PATH``
+compiles the step ahead of time on its first batch and writes the
+compiled program's text there.
+
 Memory residency: the training loop threads ONE donated ``TrainState``
 through ``jax.jit(step, donate_argnums=(0,))``.  On the resident fast
 path (``--fused multi_tensor``) the flat buffers are the single owner of
@@ -42,9 +47,9 @@ uninterrupted run (README: "Data pipeline & resumable input").
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +58,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import (AsyncCheckpointer, check_loadable,
                               load_checkpoint, load_loader_state,
                               resolve_checkpoint, save_checkpoint, step_dir)
-from repro.configs import ARCHS, get_config, smoke_variant
 from repro.core import make_optimizer
 from repro.core.optim import (FlatOptState, OptState, OptimizerSpec,
                               TrainState, builder_accepts, from_pytree,
@@ -61,6 +65,8 @@ from repro.core.optim import (FlatOptState, OptState, OptimizerSpec,
 from repro.core.transform import ChainOptState, place_chain_state
 from repro.data import (DiskShardedSource, LoaderState, PrefetchIterator,
                         StreamingLoader, SyntheticLM, device_put_batch)
+from repro.launch.common import (add_model_args, enable_compile_cache,
+                                 model_config)
 from repro.launch.mesh import (data_axes_of, init_distributed,
                                is_main_process, make_train_mesh,
                                process_count)
@@ -109,11 +115,31 @@ def _restore(path: str, params, state):
     return {"params": restored["params"], "opt": opt_state}, step
 
 
+def _compile_ahead(step, path: str, verbose: bool):
+    """Wrap a jitted step so its first call compiles it ahead of time on
+    the actual (state, batch), writes the compiled text to ``path`` and
+    reports the compile time; every call then runs that executable."""
+    exe = None
+
+    def run(state, batch):
+        nonlocal exe
+        if exe is None:
+            t0 = time.perf_counter()
+            exe = step.lower(state, batch).compile()
+            if verbose:
+                print(f"[train] step compiled in "
+                      f"{time.perf_counter() - t0:.2f}s -> {path}")
+            os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+            with open(path, "w") as f:
+                f.write(exe.as_text())
+        return exe(state, batch)
+
+    return run
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-9b", choices=sorted(ARCHS))
-    ap.add_argument("--reduced", action="store_true",
-                    help="train the smoke-scale variant (CPU-friendly)")
+    add_model_args(ap, "yi-9b")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -189,11 +215,13 @@ def main(argv=None):
                     help="append per-step metrics (loss, grad_norm, lr, "
                          "wall-clock, tokens/sec) as JSON lines to this "
                          "path via the repro.tracker JSONL backend")
+    ap.add_argument("--hlo-out", default="",
+                    help="compile the step ahead of time on the first "
+                         "batch and write the compiled program's text to "
+                         "this path")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = smoke_variant(cfg)
+    cfg = model_config(args, "train")
 
     # multi-process init FIRST — jax.devices() below must see the global
     # device set; a guarded no-op for single-process runs
@@ -201,6 +229,7 @@ def main(argv=None):
         coordinator_address=args.coordinator or None,
         num_processes=args.num_processes or None,
         process_id=args.process_id if args.process_id >= 0 else None)
+    enable_compile_cache()
     main_proc = is_main_process()
 
     n_dev = len(jax.devices())
@@ -324,6 +353,8 @@ def main(argv=None):
     step = jax.jit(make_train_step(cfg, rt, opt, n_micro=args.n_micro,
                                    grad_specs=gspecs),
                    donate_argnums=(0,))
+    if args.hlo_out:
+        step = _compile_ahead(step, args.hlo_out, main_proc)
     loader = None
     prefetcher = None
     seq = args.seq

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import interpret_mode
 from repro.models.param import ParamDef
 
 NEG_INF = -2.0e38  # large-negative for masking (fp32-safe)
@@ -217,7 +218,8 @@ def _sdpa(q, k, v, mask, cap, scale, bf16_mm: bool = False):
 
 
 # Route paged decode attention through the Pallas paged-attention
-# kernel: None = auto (TPU only), True/False = force.  The jnp
+# kernel: None = auto (the kernel on TPU, the gather path on the CPU
+# backend, any other backend raises), True/False = force.  The jnp
 # gather path below is the bitwise reference against the dense decode
 # engine; the kernel is the TPU fast path (agrees to ~1e-6 atol in
 # fp32 — online vs two-pass softmax reassociates the reduction).
@@ -226,7 +228,7 @@ PAGED_DECODE_KERNEL: Optional[bool] = None
 
 def _use_paged_kernel() -> bool:
     if PAGED_DECODE_KERNEL is None:
-        return jax.default_backend() == "tpu"
+        return not interpret_mode()
     return PAGED_DECODE_KERNEL
 
 

@@ -24,7 +24,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -183,12 +182,16 @@ def moe_apply(p, x, cfg: ModelConfig, rt: Runtime):
         body = partial(_moe_body, cfg=cfg, n_ep=n_ep, ep_axis=rt.ep_axis,
                        model_axis=rt.model_axis, n_model=n_model, mode=mode)
         wspec = P(rt.ep_axis, None, rt.model_axis)
-        y, aux = shard_map(
+        # check_vma=False: in allreduce mode y is replicated by the body's
+        # own psum over the ep and model axes, and the router aux is the
+        # same on every model shard; out_specs state both, the checker
+        # cannot infer them through the capacity scatter.
+        y, aux = jax.shard_map(
             body, mesh=rt.mesh,
             in_specs=(P(None, None), wspec, wspec,
                       P(rt.ep_axis, rt.model_axis, None), tok_spec),
             out_specs=(tok_spec, P(rt.data_axes if a2a_ok else None)),
-            check_rep=False,
+            check_vma=False,
         )(p["router"], p["wg"], p["wu"], p["wd"], x.reshape(T_global, d))
         y = y.reshape(B, S, d)
         aux = jnp.mean(aux)

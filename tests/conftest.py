@@ -6,6 +6,14 @@ import pytest
 # tests see ONE device (the dry-run sets its own 512-device flag in a
 # separate process); keep any user XLA_FLAGS out of the way.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# XLA:CPU codegen without FMA instructions (AVX has none): whether LLVM
+# contracts a*b+c into one fma, and which product it folds, depends on
+# the fusion around the expression, so the fused engine's interpret-mode
+# kernels and the jnp reference could round the same update differently
+# in the last ulp.  Without FMA both round every product and sum.
+if "--xla_cpu_max_isa" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_cpu_max_isa=AVX").strip()
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # repo root too, so the benchmarks/ namespace package (bench harness,

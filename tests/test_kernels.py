@@ -186,3 +186,27 @@ def test_paged_ref_matches_model_gather_path():
     mask = jnp.where(valid, 0.0, layers.NEG_INF)[:, None, None, :]
     o = layers._sdpa(q[:, None], kd, vd, mask, 0.0, hd ** -0.5)[:, 0]
     np.testing.assert_allclose(np.asarray(r), np.asarray(o), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# backend choice: interpret mode / gather path on the CPU backend only
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_kernels_run_on_tpu_or_interpreted_on_cpu(monkeypatch, backend,
+                                                  interpret):
+    """No silent fallback: a backend that is neither the chip nor the
+    CPU the tests use raises instead of running the hot paths on it."""
+    from repro import kernels
+    from repro.models import layers
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(layers, "PAGED_DECODE_KERNEL", None)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="not on 'gpu'"):
+            kernels.interpret_mode()
+        with pytest.raises(RuntimeError):
+            layers._use_paged_kernel()
+    else:
+        assert kernels.interpret_mode() is interpret
+        assert layers._use_paged_kernel() is (not interpret)

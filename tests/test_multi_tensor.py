@@ -187,48 +187,6 @@ def test_adam_update_kernel_matches_ref(wd):
         assert bool(jnp.array_equal(k, r)) and k.dtype == r.dtype
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_lane_pad_bitwise_identical(dtype):
-    """The real-TPU lane-width padding flag (coefficient/partial blocks
-    widened from (rows, 1) to (rows, 128)) must not change a single bit:
-    the coefficient is lane-replicated on the host, partials are
-    broadcast-stored and lane 0 sliced back out."""
-    from repro.kernels.multi_tensor import kernel as mt_kernel
-    layout = build_layout(make_tree(4, dtype))
-    (p,) = flatten(make_tree(4, dtype), layout)
-    (g,) = flatten(make_tree(5, dtype, scale=3.0), layout)
-    (u,) = flatten(make_tree(6), layout, cast_to=jnp.float32)
-    (m,) = flatten(make_tree(7), layout, cast_to=jnp.float32)
-    (v,) = flatten(jax.tree.map(jnp.abs, make_tree(8, scale=0.1)), layout,
-                   cast_to=jnp.float32)
-    a = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 13),
-                                  (p.size // CHUNK,)))
-    c = jnp.float32(0.7)
-    bc1, bc2 = jnp.float32(1 - 0.9), jnp.float32(1 - 0.999)
-    outs = {}
-    for lp in (False, True):
-        kw = dict(interpret=True, lane_pad=lp)
-        outs[lp] = (
-            (mt_kernel.chunk_sumsq(g, p, wd=1e-4, **kw),)
-            + mt_kernel.fused_update(p, g, u, a, c, beta=0.9, wd=1e-4, **kw)
-            + mt_kernel.adam_update(p, g.astype(dtype), m, v, bc1, bc2,
-                                    b1=0.9, b2=0.999, eps=1e-6, wd=1e-4,
-                                    **kw)
-            + mt_kernel.scale_apply(p, u, a, c, **kw))
-    for off, on in zip(outs[False], outs[True]):
-        assert bool(jnp.array_equal(off, on)) and off.dtype == on.dtype
-
-
-def test_lane_pad_env_default(monkeypatch):
-    from repro.kernels.multi_tensor import kernel as mt_kernel
-    monkeypatch.delenv("REPRO_MT_LANE_PAD", raising=False)
-    assert mt_kernel._lane_pad_default() is False
-    monkeypatch.setenv("REPRO_MT_LANE_PAD", "1")
-    assert mt_kernel._lane_pad_default() is True
-    monkeypatch.setenv("REPRO_MT_LANE_PAD", "0")
-    assert mt_kernel._lane_pad_default() is False
-
-
 def test_scale_apply_kernel_matches_ref():
     """The LAMB apply pass: Pallas (interpret) == jnp oracle, bitwise."""
     layout = build_layout(make_tree(4))

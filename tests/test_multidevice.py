@@ -18,6 +18,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import ARCHS, smoke_variant
@@ -51,7 +52,7 @@ SCRIPT = textwrap.dedent("""
     ts_ref, stats_ref = step_ref(opt.init_state(params), batch)
 
     # --- 4x2 mesh (data=4 with EP, model=2 TP) ---
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rt = Runtime(mesh=mesh, data_axes=("data",), remat=True)
     psh = param_shardings(defs, mesh)
     params_sharded = jax.device_put(params, psh)
@@ -83,6 +84,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import MoEConfig, ModelConfig
     from repro.models import moe
     from repro.models.param import materialize
@@ -98,7 +100,7 @@ MOE_EP_SCRIPT = textwrap.dedent("""
 
     y_ref, aux_ref = moe.moe_ref(p, x, cfg)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rt = Runtime(mesh=mesh, data_axes=("data",))
     y_ep, aux_ep = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, rt))(p, x)
     print("AUX", float(aux_ref), float(aux_ep))
@@ -117,6 +119,7 @@ FUSED_OPT_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import sngm
@@ -133,7 +136,7 @@ FUSED_OPT_SCRIPT = textwrap.dedent("""
     grads = {n: 3.0 * jax.random.normal(jax.random.fold_in(k, 100 + i), s)
              for i, (n, s) in enumerate(sorted(shapes.items()))}
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     shard = {n: NamedSharding(mesh, P("data") if len(s) == 2 else P())
              for n, s in shapes.items()}
     params_s = jax.device_put(params, shard)
@@ -164,6 +167,7 @@ RESIDENT_BF16_SHARDED_SCRIPT = textwrap.dedent("""
     import os, tempfile
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import sngm
@@ -186,7 +190,7 @@ RESIDENT_BF16_SHARDED_SCRIPT = textwrap.dedent("""
     grads = {n: (3.0 * jax.random.normal(jax.random.fold_in(k, 100 + i), s))
                 .astype(jnp.bfloat16)
              for i, (n, s) in enumerate(sorted(shapes.items()))}
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     shard = {n: NamedSharding(mesh, P("data") if len(s) == 2 else P())
              for n, s in shapes.items()}
     params_s = jax.device_put(params, shard)
@@ -233,6 +237,7 @@ TWO_LEVEL_NORM_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
 
     from repro.core.multi_tensor import (_chunk_sumsq, _engine_mesh,
                                          _leaf_values, build_layout, flatten,
@@ -240,7 +245,7 @@ TWO_LEVEL_NORM_SCRIPT = textwrap.dedent("""
                                          place_flat_state, tree_squared_norm,
                                          init_flat_state)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     S = mesh_shards(mesh)
     k = jax.random.PRNGKey(0)
 
@@ -293,6 +298,7 @@ SHARDED_RESIDENT_PARITY_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
 
     from repro.core import lamb, msgd, sngm
     from repro.core.multi_tensor import FlatOptState, mesh_shards, unflatten
@@ -313,7 +319,7 @@ SHARDED_RESIDENT_PARITY_SCRIPT = textwrap.dedent("""
             for a, b in zip(jax.tree.leaves(ta), jax.tree.leaves(tb)):
                 assert bool(jnp.array_equal(a, b)), tag
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     k = jax.random.PRNGKey(0)
     shapes = {"wq": (256, 128), "wk": (256, 128), "scale": (256,),
               "emb": (1000, 64), "bias": (7,)}
