@@ -10,9 +10,7 @@ compile-only mode.
         --steps 50 --batch 8 --seq 128 --reduced
 
 ``--n-layers N`` cuts depth at the published widths (what fits one
-chip: ``--arch yi-9b --n-layers 1`` at B=8, S=1024); ``--hlo-out PATH``
-compiles the step ahead of time on its first batch and writes the
-compiled program's text there.
+chip: ``--arch yi-9b --n-layers 1`` at B=8, S=1024).
 
 Memory residency: the training loop threads ONE donated ``TrainState``
 through ``jax.jit(step, donate_argnums=(0,))``.  On the resident fast
@@ -49,7 +47,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -80,8 +77,9 @@ from repro.tracker.callbacks import PrefetchMonitor, StepTimer
 from repro.training import make_train_step, run_steps
 
 
-def _restore(path: str, params, state):
-    """Restore {"params", "opt"} regardless of which STATE FORM the
+def _restore(path: str, params, state, mesh=None):
+    """Restore {"params", "opt"} into the shape-only templates ``params``
+    and ``state`` regardless of which STATE FORM the
     checkpoint holds (pytree form — OptState, or a ChainOptState from
     lamb / a segment-compiled chain — vs flat-buffer-resident
     FlatOptState): detect the saved form from the archive's key set, load
@@ -108,33 +106,12 @@ def _restore(path: str, params, state):
     want_flat = isinstance(state, FlatOptState)
     if saved_flat == want_flat:
         return load_checkpoint(path, {"params": params, "opt": state})
-    alt = to_pytree(state) if want_flat else from_pytree(state, params)
+    alt = jax.eval_shape(to_pytree if want_flat else from_pytree,
+                         *((state,) if want_flat else (state, params)))
     restored, step = load_checkpoint(path, {"params": params, "opt": alt})
-    opt_state = (from_pytree(restored["opt"], restored["params"])
+    opt_state = (from_pytree(restored["opt"], restored["params"], mesh=mesh)
                  if want_flat else to_pytree(restored["opt"]))
     return {"params": restored["params"], "opt": opt_state}, step
-
-
-def _compile_ahead(step, path: str, verbose: bool):
-    """Wrap a jitted step so its first call compiles it ahead of time on
-    the actual (state, batch), writes the compiled text to ``path`` and
-    reports the compile time; every call then runs that executable."""
-    exe = None
-
-    def run(state, batch):
-        nonlocal exe
-        if exe is None:
-            t0 = time.perf_counter()
-            exe = step.lower(state, batch).compile()
-            if verbose:
-                print(f"[train] step compiled in "
-                      f"{time.perf_counter() - t0:.2f}s -> {path}")
-            os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-            with open(path, "w") as f:
-                f.write(exe.as_text())
-        return exe(state, batch)
-
-    return run
 
 
 def main(argv=None):
@@ -215,10 +192,6 @@ def main(argv=None):
                     help="append per-step metrics (loss, grad_norm, lr, "
                          "wall-clock, tokens/sec) as JSON lines to this "
                          "path via the repro.tracker JSONL backend")
-    ap.add_argument("--hlo-out", default="",
-                    help="compile the step ahead of time on the first "
-                         "batch and write the compiled program's text to "
-                         "this path")
     args = ap.parse_args(argv)
 
     cfg = model_config(args, "train")
@@ -319,20 +292,23 @@ def main(argv=None):
     if args.resume:
         if not args.ckpt:
             raise SystemExit("--resume requires --ckpt")
-        restored, start = _restore(resume_path, params, state)
+        # the checkpoint loads into shape-only templates: the init
+        # buffers are released first, so a model that fills most of the
+        # chip resumes without holding two copies of its state
+        params, state = jax.eval_shape(lambda: (params, state))
+        restored, start = _restore(resume_path, params, state, mesh)
         params, state = restored["params"], restored["opt"]
+        del restored
         if mesh is not None:
             # re-place onto the mesh: load_checkpoint materialized every
-            # leaf on the default device.  Resident flat buffers are
-            # rebuilt FROM the sharded leaves (bitwise-identical values,
-            # same placement as an unresumed opt.init).
+            # leaf on the default device.  Resident flat buffers were
+            # packed for the mesh's shard count as they were built (or
+            # loaded so); placing them needs no second copy on the
+            # default device (bitwise-identical values, same placement as
+            # an unresumed opt.init).
             params = jax.device_put(params, psh)
             if isinstance(state, FlatOptState):
-                # round-trip through the pytree form (momentum or lamb's
-                # Adam-moment chain state — to_pytree picks the right one);
-                # mesh= re-packs the layout at the mesh's shard count and
-                # places the buffers, same as an unresumed opt.init
-                state = from_pytree(to_pytree(state), params, mesh=mesh)
+                state = from_pytree(state, params, mesh=mesh)
             elif isinstance(state, OptState):
                 state = OptState(state.step,
                                  jax.device_put(state.momentum, psh))
@@ -353,8 +329,6 @@ def main(argv=None):
     step = jax.jit(make_train_step(cfg, rt, opt, n_micro=args.n_micro,
                                    grad_specs=gspecs),
                    donate_argnums=(0,))
-    if args.hlo_out:
-        step = _compile_ahead(step, args.hlo_out, main_proc)
     loader = None
     prefetcher = None
     seq = args.seq

@@ -70,18 +70,26 @@ def logical_axes(tree):
     return _tree_map_defs(lambda d: d.axes, tree)
 
 
+STACKED_AXES = ("layers", "experts")
+
+
 def _fan_in(d: ParamDef) -> float:
-    """Fan-in from the logical-axis layout: 2D mats are (in, out); 3D
-    projections back to the residual stream (last axis "embed", e.g.
-    wo (H, hd, d)) contract everything before it; other 3D projections
-    (wq (d, H, hd), wk_b (lora, H, hd)) contract their first dim."""
-    if len(d.shape) < 2:
-        return float(d.shape[-1])
-    if len(d.shape) == 2:
-        return float(d.shape[0])
-    if d.axes and d.axes[-1] == "embed":
-        return float(np.prod(d.shape[:-1]))
-    return float(d.shape[0])
+    """Fan-in from the logical-axis layout, after the leading stacked
+    axes (``layers`` from ``stack``, ``experts``), which hold independent
+    copies and are not contracted: 2D mats are (in, out); 3D projections
+    back to the residual stream (last axis "embed", e.g. wo (H, hd, d))
+    contract everything before it; other 3D projections (wq (d, H, hd),
+    wk_b (lora, H, hd)) contract their first dim."""
+    shape, axes = d.shape, d.axes
+    while len(shape) > 1 and axes and axes[0] in STACKED_AXES:
+        shape, axes = shape[1:], axes[1:]
+    if len(shape) < 2:
+        return float(shape[-1])
+    if len(shape) == 2:
+        return float(shape[0])
+    if axes and axes[-1] == "embed":
+        return float(np.prod(shape[:-1]))
+    return float(shape[0])
 
 
 def _path_hash(path) -> int:

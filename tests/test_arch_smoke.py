@@ -12,7 +12,8 @@ from repro.configs import ARCHS, SHAPES, input_specs, smoke_variant
 from repro.core import sngm
 from repro.core.schedules import constant
 from repro.models import CPU_RUNTIME, forward, model_defs
-from repro.models.param import materialize
+from repro.models.param import (STACKED_AXES, ParamDef, _fan_in,
+                                materialize)
 from repro.training import make_train_step
 
 ALL_ARCHS = sorted(ARCHS)
@@ -111,3 +112,40 @@ def test_input_specs_all_combos():
                                                  shape.seq_len)
             if cfg.is_encoder_decoder:
                 assert specs["encoder_embeds"].shape[1] == cfg.encoder_len
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_input_projections_init_at_one_over_sqrt_d(built, arch):
+    """Every default-scale matrix that contracts the residual stream
+    (first axis "embed" after the stacked ``layers``/``experts`` axes)
+    starts with std 1/sqrt(d_model).  A stacked axis taken as the fan-in
+    gives std 1: saturated attention from step 0, and gradients whose
+    rounding differences grow by orders of magnitude per step."""
+    cfg, params = built(arch)
+    defs = model_defs(cfg)
+    leaves = jax.tree_util.tree_flatten_with_path(
+        defs, is_leaf=lambda x: isinstance(x, ParamDef))[0]
+    checked = 0
+    for path, d in leaves:
+        axes = [a for a in d.axes if a not in STACKED_AXES]
+        if d.init != "normal" or d.scale >= 0 or len(axes) < 2 \
+                or axes[0] != "embed":
+            continue
+        w = params
+        for k in path:
+            w = w[k.key]
+        std = float(np.std(np.asarray(w, np.float32)))
+        assert abs(std * np.sqrt(cfg.d_model) - 1.0) < 0.1, (path, std)
+        checked += 1
+    assert checked > 0
+
+
+@pytest.mark.parametrize("shape,axes,fan_in", [
+    ((2, 256, 4, 64), ("layers", "embed", "heads", "head_dim"), 256),
+    ((2, 4, 64, 256), ("layers", "heads", "head_dim", "embed"), 256),
+    ((2, 4, 256, 128), ("layers", "experts", "embed", "ffn"), 256),
+    ((2, 4, 128, 256), ("layers", "experts", "ffn", "embed"), 128),
+    ((256, 512), ("embed", "ffn"), 256),
+])
+def test_fan_in_skips_stacked_axes(shape, axes, fan_in):
+    assert _fan_in(ParamDef(shape, axes)) == fan_in

@@ -37,7 +37,8 @@ def test_refuses_without_tpu():
 def test_one_chip_phases_on_cpu(monkeypatch, tmp_path, capsys):
     import chip_smoke
     monkeypatch.setattr(chip_smoke, "PLATFORM", "cpu")
-    monkeypatch.setattr(chip_smoke, "OUT", str(tmp_path))
+    monkeypatch.setattr(chip_smoke, "OUT", str(tmp_path / "out"))
+    monkeypatch.setattr(chip_smoke, "CKPT", str(tmp_path / "ckpt"))
     monkeypatch.setattr(chip_smoke, "TRAIN", TINY_TRAIN)
     monkeypatch.setattr(chip_smoke, "SERVE", TINY_SERVE)
     chip_smoke.main([])
@@ -47,7 +48,11 @@ def test_one_chip_phases_on_cpu(monkeypatch, tmp_path, capsys):
                                "count": 1}}
     text = "\n".join(out)
     assert "depth cut to 1 of 2 layers" in text
-    assert "[train] largest relative gap: loss" in text
+    assert "[train] largest gaps: loss" in text
+    assert "[train:resumed] step 1: loss" in text
+    assert "[train:update] largest per-leaf gap" in text
+    assert "train step module holds 0 tpu_custom_call(s)" in text
+    assert not os.path.exists(tmp_path / "ckpt")
     assert "kernel vs gather largest gap" in text
 
 
@@ -58,18 +63,21 @@ FOUR_CHIPS = textwrap.dedent("""
     import chip_smoke
     chip_smoke.PLATFORM = "cpu"
     chip_smoke.OUT = {out!r}
+    chip_smoke.CKPT = {ckpt!r}
     chip_smoke.FOUR_CHIP_TRAIN = {train!r}
     chip_smoke.main(["--four-chips"])
 """)
 
 
 def test_four_chip_phase_on_cpu_devices(tmp_path):
-    script = FOUR_CHIPS.format(root=ROOT, out=str(tmp_path),
-                               train=TINY_TRAIN)
+    script = FOUR_CHIPS.format(root=ROOT, out=str(tmp_path / "out"),
+                               ckpt=str(tmp_path / "ckpt"), train=TINY_TRAIN)
     r = subprocess.run([sys.executable, "-c", script], env=_env(),
                        capture_output=True, text=True, timeout=560)
     lines = r.stdout.strip().splitlines()
     assert r.returncode == 0 and lines, r.stdout[-2000:] + r.stderr[-3000:]
     assert json.loads(lines[-1])["device"]["count"] == 4
     assert "mesh={'data': 2, 'model': 2}" in r.stdout
-    assert "[mesh2x2] largest relative gap: loss" in r.stdout
+    assert "[mesh2x2] largest gaps: loss" in r.stdout
+    assert "[mesh2x2:resumed] step 1: loss" in r.stdout
+    assert "[mesh2x2:update] largest per-leaf gap" in r.stdout

@@ -66,7 +66,11 @@ SCRIPT = textwrap.dedent("""
                         out_shardings=(ts_sh, None))
     ts_dist, stats_dist = step_dist(opt.init_state(params_sharded), batch)
 
-    l1, l2 = float(stats_ref["loss"]), float(stats_dist["loss"])
+    # the cross-entropy: the router's load-balance aux loss is a
+    # statistic of each expert-parallel shard's tokens (moe.py averages
+    # the shards' values), so the total loss differs from one device's
+    # by design
+    l1, l2 = float(stats_ref["ce_loss"]), float(stats_dist["ce_loss"])
     g1, g2 = float(stats_ref["grad_norm"]), float(stats_dist["grad_norm"])
     print("LOSS", l1, l2, "GNORM", g1, g2)
     assert abs(l1 - l2) < 1e-4 * max(1, abs(l1)), (l1, l2)

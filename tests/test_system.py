@@ -12,6 +12,7 @@ pytestmark = pytest.mark.slow  # excluded from the tier-1 fast lane
 
 from repro.configs import ARCHS, smoke_variant
 from repro.core import msgd, sngm
+from repro.core.optim import TrainState, to_pytree
 from repro.core.schedules import poly_power
 from repro.data import SyntheticLM, synthetic_images
 from repro.models import CPU_RUNTIME, model_defs
@@ -109,42 +110,58 @@ def test_flat_accumulation_bitwise_and_donation_stable(tiny_cfg):
     each micro-gradient packs into the dtype-bucketed buffers inside the
     scan, and the optimizer gets pre-packed FlatGrads) is bitwise stable
     under state donation (the launcher's production configuration) over
-    several steps, and agrees with the tree-accumulating jnp path.
+    three steps, and agrees with the tree-accumulating jnp path.
 
-    Against the jnp path the step is not bitwise: the two programs fuse
-    the backward pass differently, and XLA:CPU then sums the tied
-    embedding's gradient (a scatter-add over the tokens plus the
-    unembedding matmul's gradient) in another order, a few fp32 ulp
-    apart in that one leaf.  So that comparison is one step long, at a
-    few-ulp bound: this tiny model's SNGM trajectory amplifies an ulp
-    into percent-level gradient-norm gaps within three steps."""
+    Against the jnp path the step is not bitwise.  Run from one state,
+    loss and gradient norm are, and only the embedding leaf (its gradient
+    is a scatter-add over the tokens) lands apart, by at most 2**-27 in
+    the momentum.  Run free for three steps, the two trajectories stay
+    within a few 1e-7 of each other."""
     params = materialize(model_defs(tiny_cfg), jax.random.PRNGKey(0))
     data = SyntheticLM(tiny_cfg.vocab_size, 32, 8, branching=4)
 
-    def run(fused, donate, steps=3, n_micro=4):
+    def make(fused, donate):
         opt = sngm(poly_power(0.5, 10, 1.1), beta=0.9, fused=fused)
-        state = opt.init_state(params)
-        step = make_train_step(tiny_cfg, CPU_RUNTIME, opt, n_micro=n_micro)
-        step = (jax.jit(step, donate_argnums=(0,)) if donate
-                else jax.jit(step))
-        stats = None
-        for t in range(steps):
-            state, stats = step(state, data.batch_at(t))
-        return state.params_view, stats
+        step = make_train_step(tiny_cfg, CPU_RUNTIME, opt, n_micro=4)
+        return opt, (jax.jit(step, donate_argnums=(0,)) if donate
+                     else jax.jit(step))
 
-    p_flat, s_flat = run(fused="multi_tensor", donate=False)
-    p_flat_d, s_flat_d = run(fused="multi_tensor", donate=True)
-    for a, b in zip(jax.tree.leaves(p_flat), jax.tree.leaves(p_flat_d)):
-        assert bool(jnp.array_equal(a, b))
-    assert float(s_flat["grad_norm"]) == float(s_flat_d["grad_norm"])
+    opt, flat = make("multi_tensor", donate=False)
+    _, flat_d = make("multi_tensor", donate=True)
+    tree_opt, tree = make(None, donate=False)
+    state, state_d = opt.init_state(params), opt.init_state(params)
+    free = tree_opt.init_state(params)
+    for t in range(3):
+        batch = data.batch_at(t)
+        ref, s_tree = tree(TrainState.wrap(
+            jax.tree.map(jnp.array, state.params_view),
+            to_pytree(state.opt_state)), batch)
+        free, s_free = tree(free, batch)
+        state, s_flat = flat(state, batch)
+        state_d, s_flat_d = flat_d(state_d, batch)
+        for a, b in zip(jax.tree.leaves(state.params_view),
+                        jax.tree.leaves(state_d.params_view)):
+            assert bool(jnp.array_equal(a, b))
+        assert float(s_flat["grad_norm"]) == float(s_flat_d["grad_norm"])
 
-    p_tree, s_tree = run(fused=None, donate=False, steps=1)
-    p_flat1, s_flat1 = run(fused="multi_tensor", donate=True, steps=1)
-    for a, b in zip(jax.tree.leaves(p_tree), jax.tree.leaves(p_flat1)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
-                                   atol=1e-8)
-    assert float(s_tree["grad_norm"]) == float(s_flat1["grad_norm"])
-    assert float(s_tree["loss"]) == float(s_flat1["loss"])
+        assert float(s_tree["loss"]) == float(s_flat["loss"])
+        assert float(s_tree["grad_norm"]) == float(s_flat["grad_norm"])
+        for a, b in zip(jax.tree.leaves(ref.params_view),
+                        jax.tree.leaves(state.params_view)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-8)
+        for a, b in zip(jax.tree.leaves(ref.opt_state.momentum),
+                        jax.tree.leaves(to_pytree(state.opt_state).momentum)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-8)
+        np.testing.assert_allclose(float(s_free["loss"]),
+                                   float(s_flat["loss"]), rtol=1e-6)
+        np.testing.assert_allclose(float(s_free["grad_norm"]),
+                                   float(s_flat["grad_norm"]), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(free.params_view),
+                    jax.tree.leaves(state.params_view)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-7)
 
 
 # ---------------------------------------------------------------------------
@@ -224,6 +241,28 @@ def test_train_launcher_save_resume_loss_continuity(tmp_path):
     resumed_jnp = run(["--steps", "12", "--ckpt", str(tmp_path / "ck2"),
                        "--resume", "--fused", "none"])
     np.testing.assert_allclose(resumed_jnp, full[6:], rtol=1e-4, atol=1e-5)
+
+
+def test_resume_holds_one_copy_of_the_state(tmp_path, monkeypatch):
+    """--resume starts its first step holding no more device memory than
+    a fresh run: the init state is released before the checkpoint loads,
+    and no pytree copy outlives the resident buffers.  A resume that held
+    both did not fit one chip at yi-9b widths."""
+    from repro.launch import train
+    live, loop = [], train.run_steps
+
+    def run_steps(*a, **k):
+        live.append(sum(x.nbytes for x in jax.live_arrays()))
+        return loop(*a, **k)
+
+    monkeypatch.setattr(train, "run_steps", run_steps)
+    args = ["--arch", "gemma-2b", "--reduced", "--batch", "4", "--seq", "16",
+            "--n-micro", "2", "--optimizer", "sngm", "--fused",
+            "multi_tensor", "--total-steps", "4", "--log-every", "100",
+            "--ckpt", str(tmp_path / "ck")]
+    train.main(args + ["--steps", "1"])
+    train.main(args + ["--steps", "2", "--resume"])
+    assert live[1] <= live[0], live
 
 
 def test_lamb_fused_save_resume_loss_continuity(tmp_path):

@@ -7,10 +7,13 @@ is in the compiled program as a ``tpu_custom_call``, i.e. compiled by
 Mosaic and not interpreted.
 
 The topology is described inside a fixture: only the worker that runs
-these tests loads the TPU library.  The persistent compile cache is off
+these tests loads the TPU library.  They skip only where libtpu is not
+installed (it is pinned in requirements.txt); any other failure to
+describe the topology fails them.  The persistent compile cache is off
 around the compiles: an entry compiled here cannot be read back without
 a chip.
 """
+import importlib.util
 import os
 
 import jax
@@ -34,11 +37,10 @@ def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no TPU compiler here")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
