@@ -27,12 +27,18 @@ REPO_CACHE_DIR = os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
 
-def enable_compile_cache() -> str:
+def enable_compile_cache(profiled: bool = False) -> str:
     """Turn on the persistent compile cache; returns its directory, or
     "off" on the CPU backend, where the tests run: CPU programs compile
     in seconds, and XLA:CPU warns about host features on every reload.
     Call it after ``jax.distributed.initialize``: it asks JAX for the
-    backend."""
+    backend.  ``profiled``: a run whose trace will be read keys the cache
+    on op metadata too, since an executable loaded from the cache keeps
+    the metadata it was compiled with, and so the scopes of whatever
+    code compiled it first."""
+    if profiled:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

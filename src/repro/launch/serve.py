@@ -162,9 +162,16 @@ def main(argv=None):
     ap.add_argument("--blocks", type=int, default=0,
                     help="KV pool blocks (0 = enough for all slots)")
     ap.add_argument("--decode-chunk", type=int, default=4)
+    ap.add_argument("--profile-dir", default="",
+                    help="paged engine: write a profiler trace of the run "
+                         "after its first round (whose compiles it leaves "
+                         "out) to this directory, with the scheduler's "
+                         "serve.* spans")
     args = ap.parse_args(argv)
+    if args.profile_dir and args.engine != "paged":
+        ap.error("--profile-dir traces the paged engine's scheduler")
 
-    enable_compile_cache()
+    enable_compile_cache(profiled=bool(args.profile_dir))
     cfg = model_config(args, "serve")
     params = materialize(model_defs(cfg), jax.random.PRNGKey(0))
     ctx = args.prompt_len + args.max_new
@@ -186,7 +193,12 @@ def main(argv=None):
         t0 = time.monotonic()
         for i, p in enumerate(prompts):
             sched.submit(ServeRequest(rid=i, prompt=p, max_new=args.max_new))
-        finished = sched.run()
+        if args.profile_dir:
+            sched.step()
+            with jax.profiler.trace(args.profile_dir):
+                finished = sched.run()
+        else:
+            finished = sched.run()
         _report(finished, time.monotonic() - t0,
                 sched.stats["decode_steps"], "paged")
         print(f"[serve:paged] peak blocks {sched.stats['peak_used_blocks']}"

@@ -114,6 +114,38 @@ def _restore(path: str, params, state, mesh=None):
     return {"params": restored["params"], "opt": opt_state}, step
 
 
+# steps of a run that --profile-dir traces, counted from its first:
+# after the first step's compile, before the run's end
+PROFILE_STEPS = (3, 5)
+
+
+class _StepProfile:
+    """A step hook that wraps ``hook`` with a profiler trace of steps
+    ``first``..``last``: started after step ``first - 1``, stopped once
+    step ``last`` has run on the device, or by ``close()`` where the run
+    ends sooner."""
+
+    def __init__(self, log_dir: str, first: int, last: int, hook=None):
+        self.log_dir, self.first, self.last, self.hook = (log_dir, first,
+                                                          last, hook)
+        self.live = False
+
+    def __call__(self, t, state_ts):
+        if self.hook is not None:
+            self.hook(t, state_ts)
+        if t == self.first - 1 and self.first <= self.last:
+            jax.profiler.start_trace(self.log_dir)
+            self.live = True
+        elif t == self.last and self.live:
+            jax.block_until_ready(state_ts)
+            self.close()
+
+    def close(self):
+        if self.live:
+            jax.profiler.stop_trace()
+            self.live = False
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     add_model_args(ap, "yi-9b")
@@ -188,6 +220,11 @@ def main(argv=None):
                          "step only pays the device->host copy, never the "
                          "commit I/O")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of steps 3-5 of the run "
+                         "(after the first step's compile) to this "
+                         "directory: each step's 'train' span, and on the "
+                         "device the step's phase scopes")
     ap.add_argument("--metrics-jsonl", default="",
                     help="append per-step metrics (loss, grad_norm, lr, "
                          "wall-clock, tokens/sec) as JSON lines to this "
@@ -202,7 +239,7 @@ def main(argv=None):
         coordinator_address=args.coordinator or None,
         num_processes=args.num_processes or None,
         process_id=args.process_id if args.process_id >= 0 else None)
-    enable_compile_cache()
+    enable_compile_cache(profiled=bool(args.profile_dir))
     main_proc = is_main_process()
 
     n_dev = len(jax.devices())
@@ -437,9 +474,18 @@ def main(argv=None):
             if (t + 1) % args.save_every == 0:
                 save_step(t + 1, state_ts)
 
-    ts = run_steps(step, ts, batches, args.steps, start=start,
-                   tracker=tracker, log_every=args.log_every,
-                   callbacks=callbacks, step_hook=step_hook)
+    profile = None
+    if args.profile_dir:
+        profile = step_hook = _StepProfile(
+            args.profile_dir, start + PROFILE_STEPS[0],
+            min(start + PROFILE_STEPS[1], args.steps - 1), step_hook)
+    try:
+        ts = run_steps(step, ts, batches, args.steps, start=start,
+                       tracker=tracker, log_every=args.log_every,
+                       callbacks=callbacks, step_hook=step_hook)
+    finally:
+        if profile is not None:
+            profile.close()
     losses = mem.series("loss")
     if args.ckpt:
         # checkpoint from the LIVE TrainState.  A FlatOptState holds the

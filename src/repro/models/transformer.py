@@ -120,11 +120,13 @@ def block_apply(p, spec: LayerSpec, h, cfg: ModelConfig, rt: Runtime, *,
         return checkpoint_name(x, "tp_out") if rt.remat_policy == "save_tp" else x
 
     if spec.mixer in ("attn", "attn_local"):
-        xin = layers.apply_norm(cfg, p["attn_norm"], h)
-        fn = layers.mla_attention if cfg.mla is not None else layers.gqa_attention
-        a, c = fn(p["attn"], xin, cfg, local=(spec.mixer == "attn_local"),
-                  pos=pos, cache=(cache or {}).get("attn"))
-        h = h + _name_tp(a).astype(h.dtype)
+        with jax.named_scope("attention"):
+            xin = layers.apply_norm(cfg, p["attn_norm"], h)
+            fn = (layers.mla_attention if cfg.mla is not None
+                  else layers.gqa_attention)
+            a, c = fn(p["attn"], xin, cfg, local=(spec.mixer == "attn_local"),
+                      pos=pos, cache=(cache or {}).get("attn"))
+            h = h + _name_tp(a).astype(h.dtype)
         if build_cache:
             new_cache["attn"] = c
     else:
@@ -146,12 +148,14 @@ def block_apply(p, spec: LayerSpec, h, cfg: ModelConfig, rt: Runtime, *,
             new_cache["cross"] = {"ck": ck, "cv": cv}
 
     if spec.ffn == "dense":
-        xin = layers.apply_norm(cfg, p["ffn_norm"], h)
-        h = h + _name_tp(layers.mlp(p["ffn"], xin, cfg)).astype(h.dtype)
+        with jax.named_scope("mlp"):
+            xin = layers.apply_norm(cfg, p["ffn_norm"], h)
+            h = h + _name_tp(layers.mlp(p["ffn"], xin, cfg)).astype(h.dtype)
     elif spec.ffn == "moe":
-        xin = layers.apply_norm(cfg, p["ffn_norm"], h)
-        y, a_loss = moe.moe_apply(p["moe"], xin, cfg, rt)
-        h = h + _name_tp(y).astype(h.dtype)
+        with jax.named_scope("mlp"):
+            xin = layers.apply_norm(cfg, p["ffn_norm"], h)
+            y, a_loss = moe.moe_apply(p["moe"], xin, cfg, rt)
+            h = h + _name_tp(y).astype(h.dtype)
         aux = aux + a_loss
 
     return h, (new_cache if build_cache else None), aux
@@ -223,13 +227,14 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
     B, S = tokens.shape
     cdt = jnp.dtype(cfg.compute_dtype)
 
-    h = params["embed"][tokens].astype(cdt)
-    if cfg.embed_scale:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, cdt)
     batch_sharded = mode != "decode" or (rt.mesh is None) or all(
         (B % rt.mesh.shape[a] == 0) for a in rt.data_axes)
     hspec = (rt.data_axes if batch_sharded else None, None, None)
-    h = rt.constrain(h, *hspec)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(cdt)
+        if cfg.embed_scale:
+            h = h * jnp.asarray(cfg.d_model ** 0.5, cdt)
+        h = rt.constrain(h, *hspec)
 
     if mode == "decode":
         rope_pos = pos
@@ -317,14 +322,15 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
         # (vocab up to 256k -> full fp32 logits would be tens of GB).
         return h, None, aux_total
 
-    if mode == "prefill":
-        # serving only needs one position's logits per row: the last, or
-        # the per-row prompt end under bucket-padded batched prefill
-        h = (h[:, -1:, :] if last_pos is None
-             else h[jnp.arange(B), last_pos.astype(jnp.int32)][:, None])
-    logits = jnp.einsum("bsd,dv->bsv", h.astype(jnp.float32),
-                        unembed_matrix(params).astype(jnp.float32))
-    logits = layers.softcap(logits, cfg.final_softcap)
+    with jax.named_scope("unembed"):
+        if mode == "prefill":
+            # serving only needs one position's logits per row: the last,
+            # or the per-row prompt end under bucket-padded batched prefill
+            h = (h[:, -1:, :] if last_pos is None
+                 else h[jnp.arange(B), last_pos.astype(jnp.int32)][:, None])
+        logits = jnp.einsum("bsd,dv->bsv", h.astype(jnp.float32),
+                            unembed_matrix(params).astype(jnp.float32))
+        logits = layers.softcap(logits, cfg.final_softcap)
     return logits, new_cache, aux_total
 
 

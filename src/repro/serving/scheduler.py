@@ -30,6 +30,17 @@ decoded token, O(n_slots x ctx) cache) with:
     generated so far), bounding memory at O(used blocks) with no
     reserved worst-case allocation.
 
+Every round runs under host spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``, about a microsecond each with no
+profiler attached): ``serve.round`` with its phases as direct children
+— ``serve.admit`` (planning and allocation), ``serve.prefill`` (args
+``bucket``, ``rows``, ``real_tokens``; one ``serve.splice`` per request
+inside), ``serve.grow_blocks``, ``serve.chunk`` (dispatch),
+``serve.sync`` (the token read-back) and ``serve.emit`` — and
+``serve.sync`` (arg ``what``) round every device-to-host read.
+``stats`` counts rounds, host syncs, block-table writes, prefill real
+and slot tokens, decoded tokens and queue wait.
+
 Token streams are bitwise equal to the dense engine's at matched
 geometry (gathered length == dense context; see layers.py paged
 branches), independent of arrival order, grouping, or preemption —
@@ -39,6 +50,7 @@ fixed seed and workload reproduce exactly.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -67,6 +79,7 @@ class ServeRequest:
     done: bool = False
     # timeline (host wall clock, for latency reporting)
     t_submit: float = 0.0
+    t_admit: float = 0.0                # first admission
     t_first: float = 0.0
     t_done: float = 0.0
     token_times: List[float] = field(default_factory=list)
@@ -143,7 +156,10 @@ class PagedScheduler:
         self.finished: List[ServeRequest] = []
         self.stats = {"prefill_shapes": set(), "decode_shapes": set(),
                       "peak_used_blocks": 0, "preemptions": 0,
-                      "decode_steps": 0, "prefill_calls": 0}
+                      "decode_steps": 0, "prefill_calls": 0,
+                      "rounds": 0, "host_syncs": 0, "table_writes": 0,
+                      "prefill_real_tokens": 0, "prefill_slot_tokens": 0,
+                      "decode_tokens": 0, "queue_wait_s": 0.0}
 
     # -- submission --------------------------------------------------------
 
@@ -168,6 +184,17 @@ class PagedScheduler:
                 return b
         return self.ctx_max
 
+    @contextlib.contextmanager
+    def _sync(self, what: str):
+        """Round a device-to-host read: counted, and spanned."""
+        self.stats["host_syncs"] += 1
+        with jax.profiler.TraceAnnotation("serve.sync", what=what):
+            yield
+
+    def _set_table(self, slot: int, block_ids) -> None:
+        self.stats["table_writes"] += 1
+        self.paged = set_block_table(self.paged, slot, block_ids)
+
     def _next_rng(self):
         rng = jax.random.fold_in(self._key, self._rng_ctr)
         self._rng_ctr += 1
@@ -178,59 +205,70 @@ class PagedScheduler:
         budget allow; one batched prefill per occupied bucket.  Returns
         the number of requests admitted."""
         staged: Dict[int, List[tuple]] = {}      # bucket -> [(slot, req, plan)]
-        free = [i for i, r in enumerate(self.slots) if r is None]
-        while self.queue and free:
-            req = self.queue[0]
-            S0 = len(req.prompt)
-            shared, keys = self.alloc.plan_prompt(req.prompt)
-            need = n_blocks_for(S0, self.block_size) - len(shared)
-            if self.alloc.n_free < need:
-                for bid in shared:               # abandon: undo retains
-                    self.alloc.release(bid)
-                break                            # admission never preempts
-            self.queue.popleft()
-            ids = shared + [self.alloc.alloc() for _ in range(need)]
-            slot = free.pop(0)
-            staged.setdefault(self._bucket(S0), []).append(
-                (slot, req, ids, keys, len(shared)))
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            free = [i for i, r in enumerate(self.slots) if r is None]
+            while self.queue and free:
+                req = self.queue[0]
+                S0 = len(req.prompt)
+                shared, keys = self.alloc.plan_prompt(req.prompt)
+                need = n_blocks_for(S0, self.block_size) - len(shared)
+                if self.alloc.n_free < need:
+                    for bid in shared:           # abandon: undo retains
+                        self.alloc.release(bid)
+                    break                        # admission never preempts
+                self.queue.popleft()
+                if not req.t_admit:
+                    req.t_admit = time.monotonic()
+                    self.stats["queue_wait_s"] += req.t_admit - req.t_submit
+                ids = shared + [self.alloc.alloc() for _ in range(need)]
+                slot = free.pop(0)
+                staged.setdefault(self._bucket(S0), []).append(
+                    (slot, req, ids, keys, len(shared)))
         for bucket, group in sorted(staged.items()):
             self._prefill_group(bucket, group)
         return sum(len(g) for g in staged.values())
 
     def _prefill_group(self, bucket: int, group) -> None:
-        toks = np.zeros((self.n_slots, bucket), np.int32)
-        last = np.zeros((self.n_slots,), np.int32)
-        for i, (_, req, *_rest) in enumerate(group):
-            S0 = len(req.prompt)
-            toks[i, :S0] = req.prompt
-            last[i] = S0 - 1
-        self.stats["prefill_shapes"].add((self.n_slots, bucket))
-        self.stats["prefill_calls"] += 1
-        logits, dense = self._prefill(self.params, jnp.asarray(toks),
-                                      last_pos=jnp.asarray(last))
-        rng = self._next_rng()
-        if self.temperature == 0.0:
-            first = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        else:
-            first = sample_logits(logits[:, -1, :], rng, self.temperature,
-                                  self.top_k)
-        first = np.asarray(first)
-        now = time.monotonic()
-        for i, (slot, req, ids, keys, n_shared) in enumerate(group):
-            self.paged = set_block_table(self.paged, slot, ids)
-            self.paged = splice_prefill(self.paged, dense, i, slot, ids,
-                                        skip_blocks=n_shared)
-            for j in range(n_shared, len(keys)):   # publish full blocks (COW)
-                self.alloc.register(keys[j], ids[j])
-            self.slots[slot] = req
-            self.blocks[slot] = ids
-            self._admit_order.append((slot, req.rid))
-            req.out.append(int(first[i]))
-            req.t_first = now
-            req.token_times.append(now)
-            self.tok = self.tok.at[slot, 0].set(int(first[i]))
-            self.pos = self.pos.at[slot].set(len(req.prompt))
-            self._finish_if_done(slot, now)
+        real = sum(len(g[1].prompt) for g in group)
+        with jax.profiler.TraceAnnotation("serve.prefill", bucket=bucket,
+                                          rows=len(group), real_tokens=real):
+            toks = np.zeros((self.n_slots, bucket), np.int32)
+            last = np.zeros((self.n_slots,), np.int32)
+            for i, (_, req, *_rest) in enumerate(group):
+                S0 = len(req.prompt)
+                toks[i, :S0] = req.prompt
+                last[i] = S0 - 1
+            self.stats["prefill_shapes"].add((self.n_slots, bucket))
+            self.stats["prefill_calls"] += 1
+            logits, dense = self._prefill(self.params, jnp.asarray(toks),
+                                          last_pos=jnp.asarray(last))
+            rng = self._next_rng()
+            if self.temperature == 0.0:
+                first = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            else:
+                first = sample_logits(logits[:, -1, :], rng, self.temperature,
+                                      self.top_k)
+            with self._sync("first"):
+                first = np.asarray(first)
+            now = time.monotonic()
+            for i, (slot, req, ids, keys, n_shared) in enumerate(group):
+                with jax.profiler.TraceAnnotation("serve.splice"):
+                    self._set_table(slot, ids)
+                    self.paged = splice_prefill(self.paged, dense, i, slot,
+                                                ids, skip_blocks=n_shared)
+                    for j in range(n_shared, len(keys)):   # publish (COW)
+                        self.alloc.register(keys[j], ids[j])
+                    self.slots[slot] = req
+                    self.blocks[slot] = ids
+                    self._admit_order.append((slot, req.rid))
+                    req.out.append(int(first[i]))
+                    req.t_first = now
+                    req.token_times.append(now)
+                    self.tok = self.tok.at[slot, 0].set(int(first[i]))
+                    self.pos = self.pos.at[slot].set(len(req.prompt))
+                    self._finish_if_done(slot, now)
+        self.stats["prefill_real_tokens"] += real
+        self.stats["prefill_slot_tokens"] += self.n_slots * bucket
         self.stats["peak_used_blocks"] = max(self.stats["peak_used_blocks"],
                                              self.alloc.used_blocks)
 
@@ -262,32 +300,34 @@ class PagedScheduler:
     def _clear_slot(self, slot: int) -> None:
         self.slots[slot] = None
         # point the table at scratch and park pos at 0
-        self.paged = set_block_table(self.paged, slot, [])
+        self._set_table(slot, [])
         self.pos = self.pos.at[slot].set(0)
         self.tok = self.tok.at[slot, 0].set(0)
 
     def _grow_blocks(self) -> None:
         """Ensure every active slot owns blocks covering its next
         ``decode_chunk`` writes, preempting (latest first) on demand."""
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            take = min(self.decode_chunk, req.max_new - req.n_generated)
-            need = n_blocks_for(int(self.pos[slot]) + take, self.block_size)
-            while len(self.blocks.get(slot, [])) < need:
-                try:
-                    self.blocks[slot].append(self.alloc.alloc())
-                except PoolExhausted:
-                    # never preempt the slot we are growing unless it is
-                    # the only active one (then its own requeue frees us)
-                    if not self._preempt_one():
-                        raise
-                    if self.slots[slot] is None:   # we evicted ourselves
-                        break
+        with jax.profiler.TraceAnnotation("serve.grow_blocks"):
+            for slot, req in enumerate(self.slots):
+                if req is None:
                     continue
-            if self.slots[slot] is not None:
-                self.paged = set_block_table(self.paged, slot,
-                                             self.blocks[slot])
+                take = min(self.decode_chunk, req.max_new - req.n_generated)
+                with self._sync("pos"):
+                    pos = int(self.pos[slot])
+                need = n_blocks_for(pos + take, self.block_size)
+                while len(self.blocks.get(slot, [])) < need:
+                    try:
+                        self.blocks[slot].append(self.alloc.alloc())
+                    except PoolExhausted:
+                        # never preempt the slot we are growing unless it
+                        # is the only active one (its requeue frees us)
+                        if not self._preempt_one():
+                            raise
+                        if self.slots[slot] is None:   # evicted ourselves
+                            break
+                        continue
+                if self.slots[slot] is not None:
+                    self._set_table(slot, self.blocks[slot])
 
     # -- decode ------------------------------------------------------------
 
@@ -309,22 +349,27 @@ class PagedScheduler:
                  for r in self.slots]
         if not any(takes):
             return
-        active = jnp.asarray([[i < t for t in takes]
-                              for i in range(self.decode_chunk)])
-        rngs = jnp.stack([self._next_rng() for _ in range(self.decode_chunk)])
-        self.stats["decode_shapes"].add((self.n_slots, self.decode_chunk))
-        self.tok, self.pos, self.paged, toks = self._chunk(
-            self.params, self.paged, self.tok, self.pos, active, rngs)
+        with jax.profiler.TraceAnnotation("serve.chunk"):
+            active = jnp.asarray([[i < t for t in takes]
+                                  for i in range(self.decode_chunk)])
+            rngs = jnp.stack([self._next_rng()
+                              for _ in range(self.decode_chunk)])
+            self.stats["decode_shapes"].add((self.n_slots, self.decode_chunk))
+            self.tok, self.pos, self.paged, toks = self._chunk(
+                self.params, self.paged, self.tok, self.pos, active, rngs)
         self.stats["decode_steps"] += self.decode_chunk
-        toks = np.asarray(toks)                     # (k, n_slots) host sync
-        now = time.monotonic()
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            take = takes[slot]
-            req.out.extend(int(t) for t in toks[:take, slot])
-            req.token_times.extend([now] * take)    # chunk-granular stamps
-            self._finish_if_done(slot, now)
+        self.stats["decode_tokens"] += sum(takes)
+        with self._sync("toks"):
+            toks = np.asarray(toks)                 # (k, n_slots)
+        with jax.profiler.TraceAnnotation("serve.emit"):
+            now = time.monotonic()
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                take = takes[slot]
+                req.out.extend(int(t) for t in toks[:take, slot])
+                req.token_times.extend([now] * take)  # chunk-granular stamps
+                self._finish_if_done(slot, now)
         self.stats["peak_used_blocks"] = max(self.stats["peak_used_blocks"],
                                              self.alloc.used_blocks)
 
@@ -332,8 +377,10 @@ class PagedScheduler:
 
     def step(self) -> None:
         """One scheduler round: admit what fits, then decode a chunk."""
-        self.admit()
-        self.decode()
+        self.stats["rounds"] += 1
+        with jax.profiler.TraceAnnotation("serve.round"):
+            self.admit()
+            self.decode()
 
     def run(self) -> List[ServeRequest]:
         """Drain queue and slots to completion; returns finished requests."""

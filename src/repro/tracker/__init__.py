@@ -36,13 +36,11 @@ from __future__ import annotations
 
 import json
 import os
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 __all__ = [
     "Tracker", "NullTracker", "MemoryTracker", "StdoutTracker",
     "JsonlTracker", "CompositeTracker", "scalarize", "read_jsonl",
-    "current_tracker", "set_global_tracker", "with_tracker",
 ]
 
 
@@ -212,26 +210,3 @@ class CompositeTracker(Tracker):
         for t in self.trackers:
             t.finish()
 
-
-# -- ambient tracker ----------------------------------------------------
-# A module-level current tracker so deeply nested loops (benchmark
-# helpers) can log without threading a tracker argument through every
-# call; explicit arguments still win where they exist.
-_GLOBAL: List[Tracker] = [NullTracker()]
-
-
-def current_tracker() -> Tracker:
-    return _GLOBAL[-1]
-
-
-def set_global_tracker(tracker: Optional[Tracker]) -> None:
-    _GLOBAL[0] = tracker if tracker is not None else NullTracker()
-
-
-@contextmanager
-def with_tracker(tracker: Tracker) -> Iterator[Tracker]:
-    _GLOBAL.append(tracker)
-    try:
-        yield tracker
-    finally:
-        _GLOBAL.pop()

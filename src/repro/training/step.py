@@ -20,6 +20,15 @@ the step, and the optimizer update writes the buffers without ever
 materializing a second pytree output.  Jit it with
 ``donate_argnums=(0,)`` (what ``launch/train.py`` does) and the whole
 params+momentum update aliases in place across steps.
+
+Each phase of the step runs under a ``jax.named_scope`` —
+``params_view``, ``fwd_bwd``, ``grad_pack``, ``grad_accum`` and
+``sngm_update`` (the model adds ``embed``, ``attention``, ``mlp`` and
+``loss`` inside ``fwd_bwd``) — so a device trace attributes each op to
+a phase through its ``op_name`` metadata.  The micro-batch loop runs
+under ``grad_accum`` with the other phases nested inside it, so an op's
+phase is the innermost one on its path.  Scopes change metadata only,
+never an op or a fusion.
 """
 from __future__ import annotations
 
@@ -47,13 +56,15 @@ def loss_fn(params, batch: Dict[str, Any], cfg: ModelConfig, rt: Runtime):
         # the cast itself is shard-local.  1D params (norm scales, biases)
         # keep fp32.
         gd = jnp.dtype(rt.gather_dtype)
-        params = jax.tree.map(
-            lambda p: p.astype(gd)
-            if (p.ndim >= 2 and p.dtype == jnp.float32) else p, params)
+        with jax.named_scope("params_view"):
+            params = jax.tree.map(
+                lambda p: p.astype(gd)
+                if (p.ndim >= 2 and p.dtype == jnp.float32) else p, params)
     h, _, aux = forward(params, cfg, rt, batch["tokens"], mode="train",
                         encoder_embeds=batch.get("encoder_embeds"))
-    loss, ntok = lm_loss(h, unembed_matrix(params), batch["tokens"],
-                         batch["loss_mask"], cfg)
+    with jax.named_scope("loss"):
+        loss, ntok = lm_loss(h, unembed_matrix(params), batch["tokens"],
+                             batch["loss_mask"], cfg)
     return loss + aux, {"ce_loss": loss, "aux_loss": aux, "ntok": ntok}
 
 
@@ -106,7 +117,8 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, opt: Optimizer,
         # resident path: a read-only pytree view of the flat buffers,
         # materialized for loss_fn only (never threaded back as a live
         # second copy — the update below reads state.opt_state.p_flats)
-        params = state.params_view
+        with jax.named_scope("params_view"):
+            params = state.params_view
         B = batch["tokens"].shape[0]
         assert B % n_micro == 0, (B, n_micro)
 
@@ -133,7 +145,8 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, opt: Optimizer,
                          for f in flats)
 
         if n_micro == 1:
-            (loss, metrics), grads = grad_fn(params, batch)
+            with jax.named_scope("fwd_bwd"):
+                (loss, metrics), grads = grad_fn(params, batch)
             grads = constrain_g(grads)
         else:
             micro = jax.tree.map(
@@ -143,37 +156,48 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, opt: Optimizer,
             if flat_layout is not None:
                 def body(acc, mb):
                     g_acc, l_acc = acc
-                    (l, m), g = grad_fn(params, mb)
-                    gf = flatten(constrain_g(g), flat_layout)
-                    g_acc = constrain_flats(tuple(
-                        a + b for a, b in zip(g_acc, gf)))
+                    with jax.named_scope("fwd_bwd"):
+                        (l, m), g = grad_fn(params, mb)
+                    with jax.named_scope("grad_pack"):
+                        gf = flatten(constrain_g(g), flat_layout)
+                    with jax.named_scope("grad_accum"):
+                        g_acc = constrain_flats(tuple(
+                            a + b for a, b in zip(g_acc, gf)))
                     return (g_acc, l_acc + l), m
 
-                g0 = tuple(jnp.zeros((b.n_elems,), b.dtype)
-                           for b in flat_layout.buckets)
-                g0 = constrain_flats(g0)
+                with jax.named_scope("grad_accum"):
+                    g0 = constrain_flats(tuple(
+                        jnp.zeros((b.n_elems,), b.dtype)
+                        for b in flat_layout.buckets))
             else:
                 def body(acc, mb):
                     g_acc, l_acc = acc
-                    (l, m), g = grad_fn(params, mb)
+                    with jax.named_scope("fwd_bwd"):
+                        (l, m), g = grad_fn(params, mb)
                     g = constrain_g(g)
-                    g_acc = jax.tree.map(lambda a, b: a + b.astype(a.dtype),
-                                         g_acc, g)
+                    with jax.named_scope("grad_accum"):
+                        g_acc = jax.tree.map(
+                            lambda a, b: a + b.astype(a.dtype), g_acc, g)
                     return (constrain_g(g_acc), l_acc + l), m
 
                 # accumulator in the parameter storage dtype: fp32 models
                 # get exact accumulation; bf16-param models (jamba-398B)
                 # trade ~0.5% gradient noise for fitting the accumulator
                 # in HBM
-                g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype),
-                                  params)
-            (g_sum, l_sum), m_stack = jax.lax.scan(
-                body, (g0, jnp.zeros((), jnp.float32)), micro)
-            if flat_layout is not None:
-                grads = FlatGrads(tuple(f / n_micro for f in g_sum),
-                                  flat_layout)
-            else:
-                grads = jax.tree.map(lambda g: g / n_micro, g_sum)
+                with jax.named_scope("grad_accum"):
+                    g0 = jax.tree.map(
+                        lambda p: jnp.zeros(p.shape, p.dtype), params)
+            # the loop's own carry and counter are accumulation too; the
+            # phases inside the body nest deeper and name their ops
+            with jax.named_scope("grad_accum"):
+                (g_sum, l_sum), m_stack = jax.lax.scan(
+                    body, (g0, jnp.zeros((), jnp.float32)), micro)
+            with jax.named_scope("grad_accum"):
+                if flat_layout is not None:
+                    grads = FlatGrads(tuple(f / n_micro for f in g_sum),
+                                      flat_layout)
+                else:
+                    grads = jax.tree.map(lambda g: g / n_micro, g_sum)
             loss = l_sum / n_micro
             # every aux metric (scalar or not) keeps its global-batch
             # semantics regardless of n_micro — so `metrics` has the same
@@ -189,7 +213,8 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, opt: Optimizer,
 
             metrics = {k: combine(k, v) for k, v in m_stack.items()}
 
-        new_state, stats = opt.step_state(grads, state)
+        with jax.named_scope("sngm_update"):
+            new_state, stats = opt.step_state(grads, state)
         stats = dict(stats)
         stats["loss"] = loss
         stats.update({k: v for k, v in metrics.items() if jnp.ndim(v) == 0})
@@ -227,6 +252,10 @@ def run_steps(step_fn, state: TrainState, batches, n_steps: int, *,
     periodic (async) checkpointing, which must see the post-step state
     and the data iterator's post-step cursor together.
 
+    Each step (batch, dispatch, metrics push; not the hook) runs under
+    ``jax.profiler.StepTraceAnnotation("train", step_num=t)``, so a
+    profile taken round the loop shows its steps.
+
     This is the ONE loop the launcher, the benchmark harness, and the
     sweep share — so every run emits the same record stream regardless
     of entry point.
@@ -239,12 +268,13 @@ def run_steps(step_fn, state: TrainState, batches, n_steps: int, *,
         it = iter(batches)
         next_batch = lambda t: next(it)           # noqa: E731
     for t in range(start, n_steps):
-        try:
-            batch = next_batch(t)
-        except StopIteration:
-            break
-        state, stats = step_fn(state, batch)
-        runner.push(t, stats)
+        with jax.profiler.StepTraceAnnotation("train", step_num=t):
+            try:
+                batch = next_batch(t)
+            except StopIteration:
+                break
+            state, stats = step_fn(state, batch)
+            runner.push(t, stats)
         if step_hook is not None:
             step_hook(t, state)
     runner.close(summary)

@@ -7,8 +7,7 @@ import numpy as np
 import pytest
 
 from repro.tracker import (CompositeTracker, JsonlTracker, MemoryTracker,
-                           NullTracker, StdoutTracker, Tracker, current_tracker,
-                           read_jsonl, scalarize, with_tracker)
+                           StdoutTracker, Tracker, read_jsonl, scalarize)
 from repro.tracker.callbacks import (Callback, CallbackRunner, MetricsBuffer,
                                      StepTimer)
 
@@ -114,16 +113,6 @@ def test_stdout_tracker_rate_limits(capsys):
     assert len(lines) == 3             # steps 0, 2 + summary
     assert "step     0" in lines[0] and "step     2" in lines[1]
     assert lines[2].startswith("summary")
-
-
-def test_ambient_tracker_context():
-    assert isinstance(current_tracker(), NullTracker)
-    mem = MemoryTracker()
-    with with_tracker(mem):
-        assert current_tracker() is mem
-        current_tracker().log(0, {"x": 1})
-    assert isinstance(current_tracker(), NullTracker)
-    assert mem.steps == [(0, {"x": 1})]
 
 
 # --- callbacks ---------------------------------------------------------
