@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps against ref.py oracles,
 all in interpret mode (the kernel body executes in Python on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,8 +124,16 @@ def test_flash_attention_matches_model_sdpa():
 # paged decode attention
 # ---------------------------------------------------------------------------
 
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention.kernel import paged_decode_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
+
+# (H, K, hd): the old small MHA and GQA cases, the chat cell's MHA head
+# shape (deepseek-7b: K 32, hd 128) and yi-9b's GQA (K 4, G 8)
+PAGED_HEADS = [pytest.param(4, 4, 64, id="4-4"),
+               pytest.param(8, 2, 64, id="8-2"),
+               pytest.param(32, 32, 128, id="mha-k32"),
+               pytest.param(32, 4, 128, id="gqa-k4g8")]
 
 
 def _paged_case(B, H, K, hd, bs, nbt, i):
@@ -137,38 +147,104 @@ def _paged_case(B, H, K, hd, bs, nbt, i):
     return q, kp, vp, jnp.asarray(ids)
 
 
-@pytest.mark.parametrize("H,K", [(4, 4), (8, 2)])          # MHA and GQA
-@pytest.mark.parametrize("bs,nbt", [(8, 4), (16, 2)])
-def test_paged_attention_matches_ref(H, K, bs, nbt):
-    B, hd = 3, 64
-    q, kp, vp, bt = _paged_case(B, H, K, hd, bs, nbt, i=20)
-    # frontier at a block boundary, mid-block, and the very last slot
-    pos = jnp.asarray([0, bs, nbt * bs - 1], jnp.int32)
+def _frontiers(bs, nbt):
+    """Frontier at the first entry, at a block boundary, mid-block in
+    the last column, at the very last slot; and an inactive slot: its
+    whole table row on the scratch block 0, pos 0."""
+    pos = jnp.asarray([0, bs, (nbt - 1) * bs + bs // 2, nbt * bs - 1, 0],
+                      jnp.int32)
+    return pos, lambda bt: bt.at[4].set(0)
+
+
+@pytest.mark.parametrize("H,K,hd", PAGED_HEADS)
+@pytest.mark.parametrize("bs,nbt", [(8, 4), (16, 2), (16, 7)])
+def test_paged_attention_matches_ref(H, K, hd, bs, nbt):
+    q, kp, vp, bt = _paged_case(5, H, K, hd, bs, nbt, i=20)
+    pos, inactive = _frontiers(bs, nbt)
+    bt = inactive(bt)
     o = paged_decode_attention(q, kp, vp, bt, pos, interpret=True)
     r = paged_attention_ref(q, kp, vp, bt, pos)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
 
 
+def test_paged_cases_cover_a_partial_last_run():
+    """At the chat cell's head shape in fp32 a run copies fewer columns
+    than 7, and not a divisor of 7: the (16, 7) cases above end in a run
+    the table does not fill."""
+    P = paged_kernel.cols_per_run(16 * 32 * 128 * 4, 7)
+    assert 1 < P < 7 and 7 % P
+
+
 @pytest.mark.parametrize("kw", [dict(window=10), dict(softcap=30.0),
                                 dict(window=7, softcap=20.0)])
-def test_paged_attention_window_softcap(kw):
-    B, H, K, hd, bs, nbt = 3, 8, 2, 64, 8, 4
-    q, kp, vp, bt = _paged_case(B, H, K, hd, bs, nbt, i=30)
-    pos = jnp.asarray([5, 17, 31], jnp.int32)
+@pytest.mark.parametrize("H,K,hd,bs,nbt,pos", [
+    pytest.param(8, 2, 64, 8, 4, (5, 17, 31), id="gqa"),
+    pytest.param(32, 32, 128, 16, 7, (5, 40, 100), id="mha-k32")])
+def test_paged_attention_window_softcap(kw, H, K, hd, bs, nbt, pos):
+    q, kp, vp, bt = _paged_case(3, H, K, hd, bs, nbt, i=30)
+    pos = jnp.asarray(pos, jnp.int32)
     o = paged_decode_attention(q, kp, vp, bt, pos, interpret=True, **kw)
     r = paged_attention_ref(q, kp, vp, bt, pos, **kw)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
 
 
-def test_paged_attention_bf16():
-    B, H, K, hd, bs, nbt = 2, 4, 2, 64, 8, 3
-    q, kp, vp, bt = _paged_case(B, H, K, hd, bs, nbt, i=40)
+@pytest.mark.parametrize("H,K,hd,bs,nbt", [
+    pytest.param(4, 2, 64, 8, 3, id="gqa"),
+    pytest.param(32, 32, 128, 16, 5, id="mha-k32"),
+    pytest.param(32, 4, 128, 16, 5, id="gqa-k4g8")])
+def test_paged_attention_bf16(H, K, hd, bs, nbt):
+    q, kp, vp, bt = _paged_case(5, H, K, hd, bs, nbt, i=40)
     q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
-    pos = jnp.asarray([6, 19], jnp.int32)
+    pos, inactive = _frontiers(bs, nbt)
+    bt = inactive(bt)
     o = paged_decode_attention(q, kp, vp, bt, pos, interpret=True)
     r = paged_attention_ref(q, kp, vp, bt, pos)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(r, np.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("H,K,hd,dtype", [(32, 32, 128, jnp.bfloat16),
+                                          (32, 4, 128, jnp.bfloat16),
+                                          (8, 2, 128, jnp.float32)])
+def test_paged_kernel_reads_the_pool_in_place(H, K, hd, dtype):
+    """The pallas_call's K/V operands are the pools themselves, in
+    their own (n_blocks, bs, K, hd) shape: the wrapper transposes and
+    pads neither, so no copy of the layer pool precedes the kernel."""
+    bs, nbt = 16, 7
+    q, kp, vp, bt = _paged_case(2, H, K, hd, bs, nbt, i=60)
+    q, kp, vp = (x.astype(dtype) for x in (q, kp, vp))
+    pos = jnp.asarray([20, 100], jnp.int32)
+    closed = jax.make_jaxpr(functools.partial(paged_decode_attention,
+                                              interpret=True))(
+        q, kp, vp, bt, pos)
+    (call,) = closed.jaxpr.eqns                 # the wrapper's jit
+    inner = call.params["jaxpr"].jaxpr
+    _, k_in, v_in, _, _ = inner.invars
+    (kern,) = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+    assert kern.invars[-2:] == [k_in, v_in]
+    assert k_in.aval.shape == v_in.aval.shape == kp.shape
+    for e in inner.eqns:
+        if e.primitive.name in ("transpose", "pad"):
+            assert not {k_in, v_in} & set(e.invars), e
+
+
+@pytest.mark.parametrize("window", [0, 20])
+def test_paged_kernel_never_reads_dead_columns(window):
+    """The blocks of table columns past the frontier or wholly outside
+    the window are never copied: NaN in them does not reach the output,
+    though runs of P columns end inside the table."""
+    B, H, K, hd, bs, nbt = 4, 32, 32, 128, 16, 7
+    q, kp, vp, bt = _paged_case(B, H, K, hd, bs, nbt, i=70)
+    pos = [0, 37, 100, nbt * bs - 1]
+    r = paged_attention_ref(q, kp, vp, bt, jnp.asarray(pos), window=window)
+    dead = [int(bt[b, c]) for b, p in enumerate(pos) for c in range(nbt)
+            if not (c * bs <= p and (window == 0 or c * bs + bs > p - window
+                                     + 1))]
+    assert dead
+    kp, vp = (x.at[jnp.asarray(dead)].set(jnp.nan) for x in (kp, vp))
+    o = paged_decode_attention(q, kp, vp, bt, jnp.asarray(pos),
+                               window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
 
 
 def test_paged_ref_matches_model_gather_path():

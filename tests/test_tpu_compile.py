@@ -15,6 +15,7 @@ a chip.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +29,13 @@ from repro.kernels.paged_attention.kernel import paged_decode_attention
 BUCKET = -(-4096 * 11008 // mt.TILE) * mt.TILE
 # yi-9b attention: 32 query heads over 4 kv heads of width 128
 B, H, K, HD, BS, NBT = 4, 32, 4, 128, 16, 8
+# deepseek-7b's chat cell: 32 slots, 32 heads of 128 without grouping,
+# block 16, 96 table columns, a 3073-block layer pool
+CHAT = dict(B=32, H=32, HD=128, BS=16, NBT=96, NB=3073)
 DTYPES = [jnp.float32, jnp.bfloat16]
 KERNEL = 'custom_call_target="tpu_custom_call"'
+CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \S+ custom-call\(",
+                         re.MULTILINE)
 
 
 @pytest.fixture(scope="module")
@@ -109,3 +115,32 @@ def test_paged_decode_attention_compiles(one_chip, dtype):
     pos = _shape((B,), jnp.int32, one_chip)
     text = _compiled_text(paged_decode_attention, q, pool, pool, bt, pos)
     assert KERNEL in text
+
+
+@pytest.mark.parametrize("h,k,hd", [(8, 1, 256),       # gemma-2b
+                                    (20, 20, 64)])     # whisper-large-v3
+def test_paged_decode_attention_compiles_padded_pools(one_chip, h, k, hd):
+    """Pools whose (K, hd) are not whole tiles of the HBM layout, which
+    a DMA cannot address in place, are padded first and still compile."""
+    q = _shape((B, h, hd), jnp.bfloat16, one_chip)
+    pool = _shape((1 + B * NBT, BS, k, hd), jnp.bfloat16, one_chip)
+    bt = _shape((B, NBT), jnp.int32, one_chip)
+    pos = _shape((B,), jnp.int32, one_chip)
+    text = _compiled_text(paged_decode_attention, q, pool, pool, bt, pos)
+    assert KERNEL in text
+
+
+def test_paged_decode_attention_compiles_at_chat_size(one_chip):
+    """The whole call is one Mosaic kernel named paged_decode_attention
+    (the name the benchmark's roofline reads): no copy or transpose of
+    the pool, no second custom call beside it."""
+    c = CHAT
+    q = _shape((c["B"], c["H"], c["HD"]), jnp.bfloat16, one_chip)
+    pool = _shape((c["NB"], c["BS"], c["H"], c["HD"]), jnp.bfloat16,
+                  one_chip)
+    bt = _shape((c["B"], c["NBT"]), jnp.int32, one_chip)
+    pos = _shape((c["B"],), jnp.int32, one_chip)
+    text = _compiled_text(paged_decode_attention, q, pool, pool, bt, pos)
+    assert KERNEL in text
+    (name,) = CUSTOM_CALL.findall(text)
+    assert re.fullmatch(r"paged_decode_attention(\.\d+)?", name), name
