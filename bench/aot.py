@@ -39,7 +39,7 @@ def train(config, mix, dev):
     from repro.models.runtime import Runtime
     from repro.training import make_train_step
     from bench import common
-    cfg = common.program_config(config)
+    cfg = common.arch(config).program_config(config)
     params = abstract(model_defs(cfg))
     spec = OptimizerSpec("sngm", {
         "schedule": {"name": "poly_power", "kwargs": {
@@ -69,7 +69,7 @@ def serve(config, mix, dev):
     from repro.serving.paged_cache import n_blocks_for
     from repro.serving.scheduler import PagedScheduler
     from bench import common
-    cfg = common.program_config(config)
+    cfg = common.arch(config).program_config(config)
     params = shapes_on(abstract(model_defs(cfg)), dev)
     bs, ctx, n = mix["block_size"], mix["ctx_max"], mix["slots"]
     s = PagedScheduler(cfg, params, Runtime(mesh=None, remat=False),

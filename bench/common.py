@@ -2,7 +2,7 @@
 compile clock, seeds, files found by name, and the result line."""
 from __future__ import annotations
 
-import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -74,6 +74,27 @@ def driver(kind: str):
 def reader(metric: str):
     return load_by_path(os.path.join(BENCH_DIR, "metrics", metric + ".py"),
                         "bench_metric_" + metric.replace(".", "_"))
+
+
+def arch(config):
+    """The module of a configuration's architecture, found by the Hugging
+    Face class name in its ``architectures[0]``."""
+    return arch_named(config["architectures"][0])
+
+
+def arch_named(name: str):
+    """``bench/archs/<name>.py``: the architecture's sizes, weights,
+    program mapping, reference layers, costs and CPU cut."""
+    path = os.path.join(BENCH_DIR, "archs", name + ".py")
+    if not name.isidentifier() or not os.path.exists(path):
+        raise SystemExit(f"bench: no module for the architecture {name!r}; "
+                         f"add bench/archs/{name}.py")
+    return _load_arch(path, name)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(path: str, name: str):
+    return load_by_path(path, "bench_arch_" + name)
 
 
 def claim_devices(chips: int):
@@ -187,37 +208,6 @@ def per_layer_for(bench, workload: str):
         if workload in m.get("workloads", [workload]) and m["moves"] in ends:
             out.append(m)
     return out
-
-
-def program_config(config):
-    """The program's ModelConfig for a configuration file, depth cut and
-    every width checked against the file."""
-    from repro.configs import get_config
-    run = config["program"]
-    cfg = dataclasses.replace(get_config(run["arch"]),
-                              n_layers=config["num_hidden_layers"],
-                              param_dtype=run["param_dtype"],
-                              compute_dtype=run["compute_dtype"])
-    if run.get("smoke"):                        # CPU rehearsal only
-        cfg = dataclasses.replace(
-            cfg, d_model=config["hidden_size"],
-            n_heads=config["num_attention_heads"],
-            n_kv_heads=config["num_key_value_heads"],
-            d_ff=config["intermediate_size"],
-            vocab_size=config["vocab_size"])
-    want = {"d_model": config["hidden_size"],
-            "n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "norm_eps": config["rms_norm_eps"],
-            "rope_theta": config["rope_theta"],
-            "tie_embeddings": config["tie_word_embeddings"]}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise SystemExit(f"bench: the program's {run['arch']} differs from "
-                         f"the configuration file: {got} != {want}")
-    return cfg
 
 
 def now() -> float:

@@ -86,7 +86,7 @@ def serve(config, mix, seeds, drv, seconds):
     from bench import reference
     server = drv.Server(config, mix, seeds[0])
     server.warm(seeds[0])
-    m = weights.dims(config)
+    m = common.arch(config).dims(config)
     ok = True
     for seed in seeds:
         t0 = time.monotonic()

@@ -45,6 +45,19 @@ TRACE_SECONDS = 4.0
 WARM_THREADS = 4
 
 
+def smoke(mix):
+    """The CPU rehearsal's cut of the mix: a few slots and short
+    requests, the rates scaled to the CPU.  ``logit_gap`` was read at
+    this size on the CPU (program against the fp32 reference, and the
+    fp8 control): bf16 rounding weighs more at smoke widths than at the
+    cell's, so the cell's own limit would not fit."""
+    return dict(mix, slots=4, ctx_max=128, rate_per_s=20.0, lead_s=0.5,
+                follow_s=30.0, check_tokens=20,
+                prompt={"median": 24, "sigma": 0.5, "min": 8, "max": 64},
+                output={"median": 8, "sigma": 0.5, "min": 4, "max": 32},
+                limits={"logit_gap": 0.02})
+
+
 def lengths(rng, n, spec):
     """Lognormal lengths with the given median, clipped to [lo, hi]."""
     x = rng.lognormal(np.log(spec["median"]), spec["sigma"], n)
@@ -76,8 +89,9 @@ class Server:
         from repro.models.runtime import Runtime
         from repro.serving.paged_cache import n_blocks_for
         from repro.serving.scheduler import PagedScheduler
-        self.mix, self.m = mix, weights.dims(config)
-        self.cfg = common.program_config(config)
+        arch = common.arch(config)
+        self.mix, self.m = mix, arch.dims(config)
+        self.cfg = arch.program_config(config)
         flat = weights.make_all(config, common.seed_key(seed, 1))
         params = weights.to_program_tree(flat, abstract(model_defs(
             self.cfg)))
@@ -263,7 +277,7 @@ def sample(reqs, seed, target_tokens):
 def gaps(config, mix, seed, picked):
     """Widest gap over the picked requests' served tokens."""
     w = weights.make_all(config, common.seed_key(seed, 1))
-    m = weights.dims(config)
+    m = common.arch(config).dims(config)
     n_max = mix["output"]["max"]
     worst = 0.0
     for prompt, out in picked:

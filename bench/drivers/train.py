@@ -21,6 +21,16 @@ from bench import common, reference, trace, weights
 TRACE_SECONDS = 4.0
 
 
+def smoke(mix):
+    """The CPU rehearsal's cut of the mix: a small batch of short rows.
+    ``change_gap`` was read at this size on the CPU (program against the
+    fp32 reference, and the fp8 control): bf16 rounding weighs more at
+    smoke widths than at the cell's, so the cell's own limit would not
+    fit."""
+    return dict(mix, batch=4, seq=64, reference_rows=2,
+                limits=dict(mix["limits"], change_gap=2e-3))
+
+
 def bigram_stream(key, vocab, batch, seq, branching):
     """batch(t) -> {"tokens", "loss_mask"}: each row walks a random
     bigram chain (every token has ``branching`` successors, fixed by the
@@ -68,8 +78,9 @@ class Job:
         from repro.training import make_train_step
 
         self.config, self.mix, self.seed = config, mix, seed
-        self.m = weights.dims(config)
-        self.cfg = common.program_config(config)
+        self.arch = common.arch(config)
+        self.m = self.arch.dims(config)
+        self.cfg = self.arch.program_config(config)
         self.wkey = common.seed_key(seed, 1)
         flat = weights.make_all(config, self.wkey)
         params = weights.to_program_tree(flat, abstract(model_defs(
@@ -116,13 +127,12 @@ class Job:
         for _ in range(n - 1):
             losses.append(self.advance()["loss"])
 
-        config = self.config
+        sp = self.arch.spec(self.config)
 
         @jax.jit
         def change(state, key):
             out = {}
             flat = jax.tree_util.tree_flatten_with_path(state.params_view)[0]
-            sp = weights.spec(config)
             for p, x in flat:
                 name = weights.path_name(p)
                 shape, std = sp[name]
@@ -148,7 +158,7 @@ def reference_steps(config, mix, seed, prec="fp32", rows=None):
     each batch, to read the fault of a step that drops the rest)."""
     import jax
     import jax.numpy as jnp
-    m = weights.dims(config)
+    m = common.arch(config).dims(config)
     wkey = common.seed_key(seed, 1)
     batch = bigram_stream(common.seed_key(seed, 2), m["vocab"],
                           mix["batch"], mix["seq"], mix["branching"])

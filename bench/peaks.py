@@ -4,8 +4,12 @@ the measured work needs, computed from its shapes.
 Peaks come from one table keyed by ``device_kind``; a kind that is not
 in it is an error, never a default.  The cost functions count the work
 the algorithm requires, not what an implementation happens to do, so a
-share of a peak reads the same whatever implements the work."""
+share of a peak reads the same whatever implements the work; each
+architecture's module in ``bench/archs/`` counts its own, found by
+``m["arch"]``."""
 from __future__ import annotations
+
+from bench import common
 
 # Google Cloud documentation, "TPU v5e" (system architecture): per chip
 # 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip links.
@@ -23,21 +27,11 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def params_without_input_embedding(m: dict) -> int:
-    """Parameters that do arithmetic per token: every weight but the
-    input embedding table, which is a lookup (the unembedding counts)."""
-    d, h, k, hd, ff, L, V = (m["d"], m["h"], m["k"], m["hd"], m["ff"],
-                             m["layers"], m["vocab"])
-    per_layer = d * h * hd + 2 * d * k * hd + h * hd * d + 3 * d * ff + 2 * d
-    return L * per_layer + d + d * V
-
-
 def train_flops_per_token(m: dict, seq: int) -> float:
-    """6 N + 12 L d S (PaLM, arXiv:2204.02311, appendix B): forward and
-    backward of every weight and of causal attention.  Recomputation
-    under remat is not counted."""
-    return (6.0 * params_without_input_embedding(m)
-            + 12.0 * m["layers"] * m["h"] * m["hd"] * seq)
+    """FLOPs of one trained token at sequence length ``seq``: forward and
+    backward of the weights and of causal attention, as the
+    architecture's module counts them.  Recomputation is not counted."""
+    return common.arch_named(m["arch"]).train_flops_per_token(m, seq)
 
 
 def sngm_min_bytes(n_params: int) -> int:
@@ -47,16 +41,14 @@ def sngm_min_bytes(n_params: int) -> int:
 
 
 def decode_flops(m: dict, ctx: int) -> float:
-    """One generated token at context length ``ctx`` (keys attended):
-    2 N for the weights, 4 ctx H hd per layer for scores and values."""
-    return (2.0 * params_without_input_embedding(m)
-            + 4.0 * m["layers"] * m["h"] * m["hd"] * ctx)
+    """One generated token at context length ``ctx`` (keys attended), as
+    the architecture's module counts it."""
+    return common.arch_named(m["arch"]).decode_flops(m, ctx)
 
 
 def paged_decode_bytes(m: dict, n_keys: int, cache_bytes: int = 2) -> float:
     """What paged decode attention must read and write for one token of
-    one sequence that attends ``n_keys`` positions: K and V of those
-    positions, the query and the output, for every layer."""
-    kv = 2.0 * n_keys * m["k"] * m["hd"] * cache_bytes
-    qo = 2.0 * m["h"] * m["hd"] * cache_bytes
-    return m["layers"] * (kv + qo)
+    one sequence that attends ``n_keys`` positions, as the architecture's
+    module counts it (its cache of those positions, query and output)."""
+    return common.arch_named(m["arch"]).decode_cache_bytes(m, n_keys,
+                                                          cache_bytes)

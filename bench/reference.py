@@ -1,9 +1,10 @@
-"""The plain reference: a llama-style decoder (RMSNorm, rotary positions
-in the split-half convention, grouped-query causal attention, SwiGLU) in
-``jax.numpy``, float32 at ``highest`` matmul precision, with its loss,
-gradients and the SNGM update (Algorithm 1 of arXiv:2007.13985).  It
-imports nothing of the program and reads only the weights that
-``bench/weights.py`` makes from the seed.
+"""The plain reference in ``jax.numpy``, float32 at ``highest`` matmul
+precision: the arithmetic every architecture shares (RMSNorm, rotary
+positions in the split-half convention, causal attention), the loss,
+gradients and the SNGM update (Algorithm 1 of arXiv:2007.13985).  Each
+architecture's layers (``hidden``) are in its module in ``bench/archs/``,
+found by ``m["arch"]``.  It imports nothing of the program and reads
+only the weights that ``bench/weights.py`` makes from the seed.
 
 ``prec`` selects the arithmetic of every matrix product: "fp32" is the
 reference; "fp8" (e4m3, one scale per tensor) is the control: the same
@@ -16,6 +17,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from bench import common
 
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
@@ -87,26 +90,6 @@ def attention(q, k, v, prec):
     return jnp.concatenate(outs, axis=1)
 
 
-def hidden(w, tokens, m, prec="fp32"):
-    """Final normed hidden states (B, S, d) of token ids (B, S)."""
-    B, S = tokens.shape
-    pos = jnp.arange(S)
-    h = w["embed"][tokens]
-    for l in range(m["layers"]):
-        x = rmsnorm(h, w["blocks/L0/attn_norm/scale"][l], m["eps"])
-        q = mm("bsd,dnh->bsnh", x, w["blocks/L0/attn/wq"][l], prec)
-        k = mm("bsd,dnh->bsnh", x, w["blocks/L0/attn/wk"][l], prec)
-        v = mm("bsd,dnh->bsnh", x, w["blocks/L0/attn/wv"][l], prec)
-        q, k = rope(q, pos, m["theta"]), rope(k, pos, m["theta"])
-        o = attention(q, k, v, prec)
-        h = h + mm("bsnh,nhd->bsd", o, w["blocks/L0/attn/wo"][l], prec)
-        x = rmsnorm(h, w["blocks/L0/ffn_norm/scale"][l], m["eps"])
-        a = jax.nn.silu(mm("bsd,df->bsf", x, w["blocks/L0/ffn/wg"][l], prec))
-        a = a * mm("bsd,df->bsf", x, w["blocks/L0/ffn/wu"][l], prec)
-        h = h + mm("bsf,fd->bsd", a, w["blocks/L0/ffn/wd"][l], prec)
-    return rmsnorm(h, w["final_norm/scale"], m["eps"])
-
-
 def logits(w, h, prec="fp32"):
     return mm("bsd,dv->bsv", h, w["unembed"], prec)
 
@@ -114,9 +97,12 @@ def logits(w, h, prec="fp32"):
 def loss_sum(w, tokens, m, prec="fp32"):
     """Summed next-token cross-entropy over positions 0..S-2 of every row
     (the last position has no target)."""
-    h = hidden(w, tokens, m, prec)[:, :-1]
+    h, aux = common.arch_named(m["arch"]).hidden(w, tokens, m, prec)
+    h = h[:, :-1]
     tgt = tokens[:, 1:]
-    total = 0.0
+    # the architecture's own term of the mean token loss over these rows
+    # (an MoE's router balance loss) weighs as many positions
+    total = aux * h.shape[0] * h.shape[1]
     for s0 in range(0, h.shape[1], Q_BLOCK):
         lg = logits(w, h[:, s0:s0 + Q_BLOCK], prec)
         lse = jax.nn.logsumexp(lg, axis=-1)
@@ -199,6 +185,6 @@ def token_gaps(w, prompt, served, m, ctx, n_max, prec="fp32"):
 @functools.partial(jax.jit, static_argnames=("n_max", "mkey", "prec"))
 def _positions_logits(w, toks, first, n_max, mkey, prec):
     m = dict(mkey)
-    h = hidden(w, toks[None], m, prec)[0]
+    h = common.arch_named(m["arch"]).hidden(w, toks[None], m, prec)[0][0]
     h = jax.lax.dynamic_slice_in_dim(h, first, n_max, axis=0)
     return logits(w, h[None], prec)[0]

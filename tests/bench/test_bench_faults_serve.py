@@ -33,7 +33,7 @@ def test_fp8_control_fails_where_the_program_passes(cpu_bench):
     bench = cpu_bench.load_benchmark()
     _, config, mix = cpu_bench.cell_files(bench, "serve-ds7b-chat")
     limit = mix["limits"]["logit_gap"]
-    m = weights.dims(config)
+    m = common.arch(config).dims(config)
     worst = {"fp32": 0.0, "fp8": 0.0}
     for seed in (3, 4):
         server = drv.Server(config, mix, seed)
