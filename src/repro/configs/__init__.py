@@ -1,6 +1,6 @@
 """Architecture registry: ``get_config(name)`` / ``ARCHS``."""
 from repro.configs.base import (
-    MLAConfig, MoEConfig, ModelConfig, SSMConfig, ShapeConfig, SHAPES,
+    MLAConfig, MoEConfig, ModelConfig, RopeScaling, SSMConfig, ShapeConfig, SHAPES,
     LayerSpec, layer_pattern, input_specs, smoke_variant,
 )
 
@@ -27,6 +27,7 @@ def get_config(name: str) -> ModelConfig:
 
 __all__ = [
     "ARCHS", "get_config", "ModelConfig", "MoEConfig", "MLAConfig",
+    "RopeScaling",
     "SSMConfig", "ShapeConfig", "SHAPES", "LayerSpec", "layer_pattern",
     "input_specs", "smoke_variant",
 ]

@@ -26,11 +26,39 @@ class MoEConfig:
     n_shared: int = 0              # shared (always-on) experts
     moe_every: int = 1             # a MoE FFN every `moe_every` layers
     n_dense_prefix: int = 0        # leading layers with dense FFN instead
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 1e-2
+    capacity_factor: float = 1.25  # expert-parallel (mesh) paths only
+    router_aux_weight: float = 1e-2   # the balance loss's weight (alpha)
     # 'softmax_topk': softmax over all experts then take top-k (DeepSeek-V2)
     # 'topk_softmax': top-k logits then softmax over them (Mixtral/Jamba)
     router_mode: str = "softmax_topk"
+    # balance loss per sequence, then the mean over sequences (DeepSeek-V2
+    # ``seq_aux``); False: one loss over all tokens of the batch
+    seq_aux: bool = False
+    # the routed experts this device holds: [first_held, first_held +
+    # n_held) of n_experts; 0 holds all.  The router still scores all
+    # n_experts; assignments to experts not held add nothing here.
+    first_held: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` of type "yarn"): rotary frequencies are interpolated
+    by ``factor`` below the ``beta_slow`` correction dim, kept above the
+    ``beta_fast`` one, with a linear ramp between; cos and sin are scaled
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim), and MLA's
+    softmax scale by mscale(factor, mscale_all_dim)**2."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -71,6 +99,7 @@ class ModelConfig:
     act: str = "silu"              # silu (SwiGLU) | gelu (GeGLU)
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (MLA only)
     qk_norm: bool = False          # Chameleon-style QK RMSNorm
     attn_softcap: float = 0.0      # Gemma2 logit soft-capping (attention)
     final_softcap: float = 0.0     # Gemma2 final-logit soft-capping
@@ -223,7 +252,7 @@ def _ffn_dense_params(cfg: ModelConfig, d_ff: int) -> int:
 
 def _ffn_moe_params(cfg: ModelConfig, active_only: bool) -> int:
     m = cfg.moe
-    n_routed = m.top_k if active_only else m.n_experts
+    n_routed = m.top_k if active_only else m.held
     n = n_routed * 3 * cfg.d_model * m.d_expert
     n += m.n_shared * 3 * cfg.d_model * m.d_expert
     n += cfg.d_model * m.n_experts   # router

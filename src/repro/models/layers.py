@@ -6,12 +6,14 @@ Abstract parameter trees are built by the ``*_defs`` functions.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, RopeScaling
 from repro.kernels import interpret_mode
 from repro.models.param import ParamDef
 
@@ -62,15 +64,47 @@ def apply_norm(cfg: ModelConfig, p, x):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding, llama split-half convention.
+def yarn_mscale(factor: float, m: float) -> float:
+    """YaRN's magnitude correction, 0.1 m ln(factor) + 1 (1 when not
+    scaled)."""
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_ramp(dim: int, theta: float, s: RopeScaling) -> np.ndarray:
+    """(dim/2,) share of each rotary frequency that YaRN divides by the
+    factor: 0 above the ``beta_fast`` correction dim, 1 below the
+    ``beta_slow`` one, linear between; the correction dim of n rotations
+    over the original context is dim ln(ctx / (2 pi n)) / (2 ln theta)."""
+    def corr(n):
+        return (dim * math.log(s.original_max_position / (2 * math.pi * n))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr(s.beta_fast)), 0)
+    high = min(math.ceil(corr(s.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float32)
+    return np.clip((i - low) / (high - low), 0, 1).astype(np.float32)
+
+
+def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float,
+         scaling: Optional[RopeScaling] = None) -> jnp.ndarray:
+    """Rotary embedding, llama split-half convention, YaRN-scaled where
+    ``scaling`` is given.
 
     x: (..., S, n_heads_or_1, hd) ; pos: broadcastable to (..., S)."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is not None:
+        ramp = jnp.asarray(yarn_ramp(hd, theta, scaling))
+        freqs = freqs / scaling.factor * ramp + freqs * (1 - ramp)
     angles = pos[..., None, None].astype(jnp.float32) * freqs  # (...,S,1,half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1)
@@ -195,7 +229,19 @@ def _repeat_kv(k, H: int):
     return jnp.repeat(k, H // K, axis=2)
 
 
-def _sdpa(q, k, v, mask, cap, scale, bf16_mm: bool = False):
+def _softmax_rowmax_apart(s):
+    """Softmax over the last axis with the row max taken in a pass of its
+    own.  Fused with the subtraction, XLA's TPU backend turns max-then-
+    broadcast into a reduce-window two rows wide, whose cost grows with
+    the square of the row: 94 ms for one (2, 16, 1024, 8192) chunk of
+    scores on a v5e, against ~1 ms for a pass over it."""
+    m = jax.lax.optimization_barrier(jnp.max(s, axis=-1, keepdims=True))
+    e = jnp.exp(s - jax.lax.stop_gradient(m))
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+def _sdpa(q, k, v, mask, cap, scale, bf16_mm: bool = False,
+          softmax=jax.nn.softmax):
     """q: (B,S,H,hd)  k,v: (B,T,K,hd), K | H.  mask: broadcast (B,H,S,T).
 
     bf16_mm (§Perf): QK^T and PV run bf16-in/f32-accumulate (the MXU's
@@ -212,7 +258,7 @@ def _sdpa(q, k, v, mask, cap, scale, bf16_mm: bool = False):
         s = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32) * scale,
                        k.astype(jnp.float32))
     s = softcap(s, cap) + mask
-    p = jax.nn.softmax(s, axis=-1)
+    p = softmax(s)
     o = jnp.einsum("bhst,bthd->bshd", p.astype(v.dtype), v)
     return o
 
@@ -272,7 +318,8 @@ def _sdpa_seq(q, k, v, causal: bool, window: int, cap, scale,
     """Full-sequence attention, chunked over the query dim: scores exist
     only per (Q_CHUNK, T) block (XLA-level flash attention; a (S,T) score
     tensor or mask at 32k would be tens of GB).  Each chunk is
-    ``jax.checkpoint``ed so backward recomputes its scores."""
+    ``jax.checkpoint``ed so backward recomputes its scores, and takes its
+    softmax's row max apart (``_softmax_rowmax_apart``)."""
     B, S, H, hd = q.shape
     hd_v = v.shape[-1]          # MLA: qk dim (192) != v head dim (128)
     T = k.shape[1]
@@ -285,7 +332,8 @@ def _sdpa_seq(q, k, v, causal: bool, window: int, cap, scale,
     def chunk(c, q_c):
         mask = (_chunk_mask(c * Q_CHUNK, Q_CHUNK, T, causal, window)
                 if (causal or window) else jnp.zeros((), jnp.float32))
-        return _sdpa(q_c, k, v, mask, cap, scale, bf16_mm)
+        return _sdpa(q_c, k, v, mask, cap, scale, bf16_mm,
+                     softmax=_softmax_rowmax_apart)
 
     chunk = jax.checkpoint(chunk, static_argnums=())
 
@@ -311,6 +359,7 @@ def gqa_attention(p, x, cfg: ModelConfig, *, local: bool, pos, cache=None,
     B = x.shape[0]
     xc = x.astype(cdt)
     cross = kv_input is not None
+    assert cfg.rope_scaling is None, "rope scaling is implemented for MLA only"
     window = (cfg.window if local else 0)
 
     q = jnp.einsum("bsd,dkh->bskh", xc, p["wq"].astype(cdt))
@@ -386,6 +435,16 @@ def _mla_q(p, xc, cfg, cdt):
     return q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times mscale(factor, mscale_all_dim)^2
+    under YaRN (DeepSeek-V2's ``softmax_scale``)."""
+    m, s = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(p, x, cfg: ModelConfig, *, local: bool, pos, cache=None):
     """MLA: full-seq path decompresses K/V; decode path runs *absorbed*
     attention directly in the kv_lora latent space, caching only
@@ -395,7 +454,7 @@ def mla_attention(p, x, cfg: ModelConfig, *, local: bool, pos, cache=None):
     H = cfg.n_heads
     B = x.shape[0]
     xc = x.astype(cdt)
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     window = (cfg.window if local else 0)
 
     q_nope, q_rope = _mla_q(p, xc, cfg, cdt)
@@ -404,8 +463,9 @@ def mla_attention(p, x, cfg: ModelConfig, *, local: bool, pos, cache=None):
     k_rope = kv_a[..., m.kv_lora_rank:]                     # shared across heads
 
     pos2 = pos if pos.ndim == 2 else pos[:, None]
-    q_rope = rope(q_rope, pos2, cfg.rope_theta)
-    k_rope = rope(k_rope[..., None, :], pos2, cfg.rope_theta)[..., 0, :]
+    q_rope = rope(q_rope, pos2, cfg.rope_theta, cfg.rope_scaling)
+    k_rope = rope(k_rope[..., None, :], pos2, cfg.rope_theta,
+                  cfg.rope_scaling)[..., 0, :]
 
     if cache is None:  # full-sequence: decompress (standard MHA form)
         S = x.shape[1]

@@ -110,9 +110,10 @@ def _cross_attend(p, x, cfg, ck, cv):
 
 def block_apply(p, spec: LayerSpec, h, cfg: ModelConfig, rt: Runtime, *,
                 pos, cache=None, build_cache: bool, encoder_out=None):
-    """Returns (h, new_cache_or_None, aux_loss)."""
+    """Returns (h, new_cache_or_None, aux): aux is ``_zero_aux(cfg)``'s
+    dict, the block's router balance loss and MoE counters."""
     new_cache: Dict[str, Any] = {}
-    aux = jnp.zeros((), jnp.float32)
+    aux = _zero_aux(cfg)
 
     from jax.ad_checkpoint import checkpoint_name
 
@@ -154,11 +155,24 @@ def block_apply(p, spec: LayerSpec, h, cfg: ModelConfig, rt: Runtime, *,
     elif spec.ffn == "moe":
         with jax.named_scope("mlp"):
             xin = layers.apply_norm(cfg, p["ffn_norm"], h)
-            y, a_loss = moe.moe_apply(p["moe"], xin, cfg, rt)
+            y, a_loss, stats = moe.moe_apply(p["moe"], xin, cfg, rt)
             h = h + _name_tp(y).astype(h.dtype)
-        aux = aux + a_loss
+        aux = {"aux_loss": a_loss, **stats}
 
     return h, (new_cache if build_cache else None), aux
+
+
+def _zero_aux(cfg: ModelConfig):
+    """What a block adds up besides its states: the router balance loss,
+    and for a model with MoE layers their counters (``moe.load_stats``)."""
+    aux = {"aux_loss": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None:
+        aux.update(moe.zero_stats())
+    return aux
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -210,9 +224,14 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
             last_pos=None):
     """mode: "train" | "prefill" | "decode".
 
-    train:   tokens (B,S)             -> (logits, None, aux)
+    train:   tokens (B,S)             -> (h, None, aux)
     prefill: tokens (B,S)             -> (logits, cache, aux)
     decode:  tokens (B,1), pos (B,)   -> (logits, cache', aux)
+
+    ``aux`` is a dict of scalars: ``aux_loss``, the router balance loss
+    summed over layers, and in a model with MoE layers ``moe_held_rows``
+    (assignments to held experts, summed over layers) and
+    ``moe_load_max_over_mean`` (its mean over the MoE layers).
 
     ``last_pos`` (B,), prefill only: per-row position whose logits to
     return instead of the last one — bucket-padded batched prefill
@@ -245,7 +264,7 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
     if cfg.is_encoder_decoder and encoder_embeds is not None:
         encoder_out = encode(params, cfg, rt, encoder_embeds)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _zero_aux(cfg)
     new_cache: Dict[str, Any] = {}
 
     # --- unrolled prefix layers ---
@@ -254,7 +273,7 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
         h, c, aux = block_apply(params["prefix"][f"P{i}"], spec, h, cfg, rt,
                                 pos=rope_pos, cache=c_in, build_cache=build_cache,
                                 encoder_out=encoder_out)
-        aux_total += aux
+        aux_total = _add(aux_total, aux)
         if build_cache:
             new_cache.setdefault("prefix", {})[f"P{i}"] = c
 
@@ -279,7 +298,7 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
                     policy = checkpoint_policies.save_only_these_names("tp_out")
                 run_block = jax.checkpoint(run_block, policy=policy)
             hh, c, aux = run_block(p_period[f"L{i}"], hh)
-            aux_acc = aux_acc + aux
+            aux_acc = _add(aux_acc, aux)
             if build_cache:
                 cs_out[f"L{i}"] = c
         hh = rt.constrain(hh, *hspec)
@@ -315,6 +334,10 @@ def forward(params, cfg: ModelConfig, rt: Runtime, tokens, *,
                                              (tail, None))
 
     h = layers.apply_norm(cfg, params["final_norm"], h)
+    if cfg.moe is not None:
+        n_moe = sum(sp.ffn == "moe" for sp in prefix) + n_periods * sum(
+            sp.ffn == "moe" for sp in period)
+        aux_total["moe_load_max_over_mean"] /= max(n_moe, 1)
 
     if mode == "train":
         # Return hidden states; the loss computes the vocab projection in

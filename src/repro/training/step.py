@@ -65,7 +65,7 @@ def loss_fn(params, batch: Dict[str, Any], cfg: ModelConfig, rt: Runtime):
     with jax.named_scope("loss"):
         loss, ntok = lm_loss(h, unembed_matrix(params), batch["tokens"],
                              batch["loss_mask"], cfg)
-    return loss + aux, {"ce_loss": loss, "aux_loss": aux, "ntok": ntok}
+    return loss + aux["aux_loss"], {"ce_loss": loss, **aux, "ntok": ntok}
 
 
 # How loss_fn's aux metrics combine across micro-batches, so logged stats
@@ -75,7 +75,7 @@ def loss_fn(params, batch: Dict[str, Any], cfg: ModelConfig, rt: Runtime):
 # loss_mask density is ragged across micro-batches); everything else is a
 # plain mean.  Extend these when adding a metric to loss_fn, or it will
 # be silently averaged under gradient accumulation.
-COUNT_METRICS = ("ntok",)
+COUNT_METRICS = ("ntok", "moe_held_rows")
 TOKEN_WEIGHTED_METRICS = ("ce_loss",)
 
 

@@ -106,15 +106,28 @@ MOE_EP_SCRIPT = textwrap.dedent("""
 
     mesh = make_mesh((4, 2), ("data", "model"))
     rt = Runtime(mesh=mesh, data_axes=("data",))
-    y_ep, aux_ep = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, rt))(p, x)
+    def counters(x):
+        # every expert is held on the mesh: the routing's own counts
+        ids = jax.lax.top_k(x.reshape(-1, 64) @ p["router"], 2)[1]
+        return moe.load_stats(moe.held_counts(ids, cfg))
+
+    y_ep, aux_ep, st = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, rt))(p, x)
     print("AUX", float(aux_ref), float(aux_ep))
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref), atol=1e-4)
+    want = counters(x)
+    assert int(st["moe_held_rows"]) == int(want["moe_held_rows"]) == 8 * 16 * 2
+    np.testing.assert_allclose(float(st["moe_load_max_over_mean"]),
+                               float(want["moe_load_max_over_mean"]))
 
     # allreduce mode: batch=2 tokens, not divisible by data=4
     x2 = x[:2, :1]
     y_ref2, _ = moe.moe_ref(p, x2, cfg)
-    y_ep2, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, rt))(p, x2)
+    y_ep2, _, st2 = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, rt))(p, x2)
     np.testing.assert_allclose(np.asarray(y_ep2), np.asarray(y_ref2), atol=1e-4)
+    want2 = counters(x2)
+    assert int(st2["moe_held_rows"]) == int(want2["moe_held_rows"]) == 2 * 2
+    np.testing.assert_allclose(float(st2["moe_load_max_over_mean"]),
+                               float(want2["moe_load_max_over_mean"]))
     print("MOE-EP-OK")
 """)
 

@@ -144,3 +144,29 @@ def test_paged_decode_attention_compiles_at_chat_size(one_chip):
     assert KERNEL in text
     (name,) = CUSTOM_CALL.findall(text)
     assert re.fullmatch(r"paged_decode_attention(\.\d+)?", name), name
+
+
+# deepseek-v2-lite's held experts: 8 of width 1408 over d_model 2048, a
+# buffer of 2 x 8192 tokens x 6 assignments (train-dsv2lite-8k)
+MOE = dict(E=8, D=2048, F=1408, ROWS=2 * 8192 * 6)
+
+
+@pytest.mark.parametrize("k,n", [(MOE["D"], MOE["F"]),     # gate and up
+                                 (MOE["F"], MOE["D"])],    # down
+                         ids=["d_to_f", "f_to_d"])
+def test_grouped_matmul_compiles_forward_and_backward(one_chip, monkeypatch,
+                                                      k, n):
+    """The held experts' grouped products at deepseek-v2-lite widths, and
+    their backward (the transposed product and the per-expert weight
+    gradient), each a Mosaic kernel."""
+    from repro.kernels.grouped_matmul import ops
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    x = _shape((MOE["ROWS"], k), jnp.bfloat16, one_chip)
+    w = _shape((MOE["E"], k, n), jnp.bfloat16, one_chip)
+    sizes = _shape((MOE["E"],), jnp.int32, one_chip)
+
+    def loss(x, w, sizes):
+        return jnp.sum(jnp.square(ops.gmm(x, w, sizes).astype(jnp.float32)))
+    text = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w,
+                          sizes)
+    assert text.count(KERNEL) == 3
